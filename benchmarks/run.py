@@ -84,6 +84,8 @@ def main() -> None:
     import importlib
 
     from benchmarks.common import rows
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     failures = []
     report: dict[str, dict] = {}
     selected = [(name, module) for name, module in SUITES

@@ -137,6 +137,13 @@ def calibrate_device(apply_fn: Callable[[dict], object],
                     rt.submit(qid, batch, b)
                     qid += 1
                 rt.drain()
+                # a failed request completes at once and would read as a
+                # fast device: a curve measured from errors is no curve
+                err = next((rt.record(q).error for q in range(q0, qid)
+                            if rt.record(q).error is not None), None)
+                if err is not None:
+                    raise RuntimeError(f"calibration request at bucket {b} "
+                                       f"failed: {err}")
                 t0 = min(rt.record(q).t_arrival for q in range(q0, qid))
                 t1 = max(rt.record(q).t_done for q in range(q0, qid))
                 if rep >= warmup_bursts:

@@ -227,6 +227,10 @@ class WorkerSupervisor:
         # source root from its __path__ so the child resolves the same code
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        # every registered model stands in for a CPU server node: pin the
+        # platform rather than inherit the parent's, or on an accelerator
+        # host the first worker would take the chip from the rest
+        env["JAX_PLATFORMS"] = "cpu"
         return env
 
     def _await_port(self, proc: subprocess.Popen) -> int:

@@ -135,12 +135,18 @@ GPU_1080TI = dict(peak_flops=11.3e12, mem_bw=484e9, xfer_bw=12e9,
                   overhead_s=2.5e-3)
 TPU_V5E = dict(peak_flops=197e12, mem_bw=819e9, xfer_bw=50e9,
                overhead_s=0.5e-3)
+ACCELERATORS = {"gpu": GPU_1080TI, "tpu": TPU_V5E}
 
 
 def accelerator_model(cfg, kind: str = "gpu") -> AnalyticalDeviceModel:
-    """Build the accelerator model for a recsys config from analytic costs."""
+    """Build the accelerator model for a recsys config from analytic costs.
+    ``kind`` names a preset in ``ACCELERATORS``; any other kind raises."""
     from repro.core import costs
-    hw = GPU_1080TI if kind == "gpu" else TPU_V5E
+    try:
+        hw = ACCELERATORS[kind]
+    except KeyError:
+        raise ValueError(f"unknown accelerator kind {kind!r}; "
+                         f"choose from {sorted(ACCELERATORS)}") from None
     return AnalyticalDeviceModel(
         flops_per_sample=costs.recsys_flops_per_sample(cfg),
         mem_bytes_per_sample=costs.recsys_embed_bytes_per_sample(cfg),

@@ -1,16 +1,17 @@
 """Pallas TPU embedding-bag kernel (the paper's dominant operator for
 DLRM-RMC1/2 and DIN — Fig. 3 "embedding dominated").
 
-TPU adaptation of the CPU gather+pool loop: the table lives in HBM and rows
-stream into VMEM one (1, D) block per grid step, selected by the
-scalar-prefetched index array (``PrefetchScalarGridSpec``) — the TPU-native
-replacement for irregular cache-resident gathers.  The grid is
-(bag_tile, hotness, row_in_tile); TPU grids execute sequentially, so pooling
-accumulates in the output VMEM block, which stays resident across all
-(hotness × tile) steps of one bag tile: bytes moved = H rows fetched + 1
-output row written per bag — the streaming minimum.
+TPU adaptation of the CPU gather+pool loop.  The table stays in HBM
+(``memory_space=pl.ANY``) and is never blocked: a (1, D) row block would
+break the TPU's (8, 128) tiling rule, so each indexed row is copied by its
+own DMA into a VMEM scratch instead.  The grid tiles the bags, TILE_B at a
+time; the tile's (TILE_B, H) ids arrive in SMEM.  Hotness step k fetches
+one row per bag of the tile into one of two VMEM slots while the rows of
+step k-1 are summed, so the gather overlaps the pooling.  Bytes moved =
+H rows fetched + 1 output row written per bag — the streaming minimum.
 
-D is padded to the 128-lane boundary by the wrapper in ``ops.py``.
+D is padded to the 128-lane boundary and B to a multiple of TILE_B by the
+wrapper in ``ops.py``, which also clamps ids into the table.
 """
 from __future__ import annotations
 
@@ -24,51 +25,59 @@ def embedding_bag(table: jax.Array, idx: jax.Array, *, mode: str = "sum",
                   tile_b: int = 8, interpret: bool = False) -> jax.Array:
     """table (V, D), idx (B, H) int32 → (B, D) pooled (sum/mean).
 
-    B must be a multiple of ``tile_b`` and D a multiple of 128 (``ops``
-    pads); V is unconstrained (rows stream from HBM).
+    B must be a multiple of ``tile_b``, D a multiple of 128 and every id in
+    [0, V) (``ops`` pads and clamps); V is unconstrained (rows stream from
+    HBM).
     """
     b, h = idx.shape
-    v, d = table.shape
-    assert b % tile_b == 0, (b, tile_b)
+    _, d = table.shape
+    if b % tile_b:
+        raise ValueError(f"batch {b} is not a multiple of tile_b {tile_b}")
 
-    grid = (b // tile_b, h, tile_b)
+    def kernel(idx_ref, table_ref, out_ref, buf, sem):
+        def row_copy(k, i, slot):
+            return pltpu.make_async_copy(
+                table_ref.at[pl.ds(idx_ref[i, k], 1)],
+                buf.at[slot, pl.ds(i, 1)], sem.at[slot])
 
-    def row_index(bt, hh, i, idx_ref):
-        # dynamic row select from the scalar-prefetched indices
-        return (idx_ref[bt * tile_b + i, hh], 0)
+        def fetch(k, slot):
+            for i in range(tile_b):
+                row_copy(k, i, slot).start()
 
-    def out_index(bt, hh, i, idx_ref):
-        return (bt, 0)
+        fetch(0, 0)
 
-    def kernel(idx_ref, row_ref, out_ref, comp_ref):
-        hh = pl.program_id(1)
-        i = pl.program_id(2)
+        def step(k, carry):
+            acc, comp = carry
+            slot = k % 2
 
-        @pl.when((hh == 0) & (i == 0))
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-            comp_ref[...] = jnp.zeros_like(comp_ref)
+            @pl.when(k + 1 < h)
+            def _prefetch():
+                fetch(k + 1, 1 - slot)
 
-        # Kahan-compensated f32 accumulation (comp_ref carries the rounding
-        # error of each partial sum).  Plain running `+=` drifts by an ulp
-        # per step, which shows against the oracle when the H rows nearly
-        # cancel — and bf16 tables would lose ~2^-8 per step uncompensated.
-        row = row_ref[0, :].astype(jnp.float32)
-        y = row - comp_ref[i, :]
-        acc = out_ref[i, :]
-        t = acc + y
-        comp_ref[i, :] = (t - acc) - y
-        out_ref[i, :] = t
+            for i in range(tile_b):
+                row_copy(k, i, slot).wait()
+            # Kahan-compensated f32 accumulation (comp carries the rounding
+            # error of each partial sum).  Plain running `+=` drifts by an
+            # ulp per step, which shows against the oracle when the H rows
+            # nearly cancel — and bf16 tables would lose ~2^-8 per step
+            # uncompensated.
+            y = buf[slot].astype(jnp.float32) - comp
+            t = acc + y
+            return t, (t - acc) - y
+
+        zeros = jnp.zeros((tile_b, d), jnp.float32)
+        acc, _ = jax.lax.fori_loop(0, h, step, (zeros, zeros))
+        out_ref[...] = acc
 
     out = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, d), row_index)],
-            out_specs=pl.BlockSpec((tile_b, d), out_index),
-            scratch_shapes=[pltpu.VMEM((tile_b, d), jnp.float32)],
-        ),
+        grid=(b // tile_b,),
+        in_specs=[pl.BlockSpec((tile_b, h), lambda bt: (bt, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tile_b, d), lambda bt: (bt, 0)),
+        scratch_shapes=[pltpu.VMEM((2, tile_b, d), table.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
         interpret=interpret,
     )(idx, table)

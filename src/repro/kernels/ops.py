@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Each wrapper: pads to the kernel's tiling constraints (lane = 128, batch
-tiles), dispatches to the Pallas kernel on TPU (or with interpret=True when
-asked), and falls back to the jnp oracle elsewhere — so the same call sites
-run everywhere and the kernels engage exactly on the target hardware.
+Each wrapper pads to the kernel's tiling constraints (lane = 128, batch
+tiles) and always runs the Pallas kernel — compiled for the TPU, or in
+the Pallas interpreter with ``interpret=True``.  There is no backend
+switch: a caller that wants the jnp oracle calls ``kernels.ref``.
 """
 from __future__ import annotations
 
@@ -16,13 +16,8 @@ from repro.kernels import cin as cin_k
 from repro.kernels import embedding_bag as eb_k
 from repro.kernels import flash_attention as fa_k
 from repro.kernels import interaction as ix_k
-from repro.kernels import ref
 
 _LANE = 128
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -34,29 +29,23 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "use_pallas", "interpret"))
-def embedding_bag(table, idx, *, mode: str = "sum", use_pallas: bool | None = None,
-                  interpret: bool = False):
-    """(V, D), (B, H) → (B, D)."""
-    use = _on_tpu() or interpret if use_pallas is None else use_pallas
-    if not use:
-        return ref.embedding_bag(table, idx, mode=mode)
+@functools.partial(jax.jit, static_argnames=("mode", "interpret"))
+def embedding_bag(table, idx, *, mode: str = "sum", interpret: bool = False):
+    """(V, D), (B, H) → (B, D).  Ids outside [0, V) are clamped into the
+    table: the kernel DMAs each row, and an out-of-range DMA faults."""
     b, _ = idx.shape
-    d = table.shape[1]
+    v, d = table.shape
     tp = _pad_to(table, 1, _LANE)
-    tile_b = 8 if b % 8 == 0 else (4 if b % 4 == 0 else (2 if b % 2 == 0 else 1))
-    out = eb_k.embedding_bag(tp, idx, mode=mode, tile_b=tile_b,
+    tile_b = 8
+    ip = _pad_to(jnp.clip(idx, 0, v - 1), 0, tile_b)
+    out = eb_k.embedding_bag(tp, ip, mode=mode, tile_b=tile_b,
                              interpret=interpret)
-    return out[:, :d]
+    return out[:b, :d]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def dot_interaction(feats, *, use_pallas: bool | None = None,
-                    interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dot_interaction(feats, *, interpret: bool = False):
     """(B, F, D) → (B, F(F-1)/2)."""
-    use = _on_tpu() or interpret if use_pallas is None else use_pallas
-    if not use:
-        return ref.dot_interaction_packed(feats)
     b = feats.shape[0]
     fp = _pad_to(feats, 2, _LANE)
     tile_b = 32 if b % 32 == 0 else (8 if b % 8 == 0 else (2 if b % 2 == 0 else 1))
@@ -65,13 +54,9 @@ def dot_interaction(feats, *, use_pallas: bool | None = None,
     return out[:b]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def cin_layer(x0, xk, w, *, use_pallas: bool | None = None,
-              interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def cin_layer(x0, xk, w, *, interpret: bool = False):
     """(B, F, D), (B, H, D), (H·F, Hn) → (B, Hn, D)."""
-    use = _on_tpu() or interpret if use_pallas is None else use_pallas
-    if not use:
-        return ref.cin_layer(x0, xk, w)
     b, _, d = x0.shape
     tile_d = _LANE
     x0p = _pad_to(x0, 2, tile_d)
@@ -82,13 +67,9 @@ def cin_layer(x0, xk, w, *, use_pallas: bool | None = None,
     return out[:, :, :d]
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def decode_attention(q, k, v, pos, *, use_pallas: bool | None = None,
-                     interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_attention(q, k, v, pos, *, interpret: bool = False):
     """q (B, Hq, D), k/v (B, T, Hkv, D), pos (B,) → (B, Hq, D)."""
-    use = _on_tpu() or interpret if use_pallas is None else use_pallas
-    if not use:
-        return ref.decode_attention(q, k, v, pos)
     t = k.shape[1]
     tile_t = 128 if t % 128 == 0 else (64 if t % 64 == 0 else t)
     return fa_k.decode_attention(q, k, v, pos, tile_t=tile_t,

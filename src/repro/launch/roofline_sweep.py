@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+# the 512 devices are CPU host devices: pin the platform, so that on an
+# accelerator host this script never claims the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 _DOC = """Exact roofline accounting (single-pod, per the assignment).
 

@@ -449,6 +449,8 @@ def main(argv=None) -> None:
                          "port (chaos harness: a pathologically slow "
                          "model load)")
     args = ap.parse_args(argv)
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     serve_worker(args.model, host=args.host, port=args.port,
                  n_workers=args.workers, batch_size=args.batch_size,
                  max_bucket=args.max_bucket, max_frame=args.max_frame,

@@ -1,7 +1,9 @@
 """Shared utilities: pytree accounting, rng, timing."""
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 from typing import Any, Callable, Iterator
 
 import jax
@@ -9,6 +11,23 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+# src/repro/utils/__init__.py → the checkout root
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    other directory is set.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``: the directory is part of what a later run
+    must find again, so it is never built from a temporary name, a pid or
+    the time.  Entry points call this; importing a module never does.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_CHECKOUT / ".jax_cache"))
 
 
 def param_count(params: PyTree) -> int:

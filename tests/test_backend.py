@@ -144,6 +144,22 @@ def _live_backend(clock, pool="live", index_in_pool=0):
                            clock=clock, own_runtime=True)
 
 
+def test_calibrate_device_fails_on_errored_requests():
+    """The runtime turns an apply_fn exception into a record error that
+    completes at once; calibration must raise, not time the errors."""
+    from repro.cluster import calibrate_device
+    ok = _tiny_apply()
+
+    def apply_fn(batch):
+        if batch["x"].shape[0] >= 4:
+            raise RuntimeError("device halted")
+        return ok(batch)
+
+    with pytest.raises(RuntimeError, match="bucket 4 failed: .*halted"):
+        calibrate_device(apply_fn, _make_batch, max_bucket=8, burst=2,
+                         reps=1)
+
+
 def test_live_backend_completes_trace_in_trace_time():
     times = np.linspace(0.0, 0.3, 30)
     sizes = np.full(30, 20, np.int64)              # 2 requests each

@@ -1,0 +1,81 @@
+"""Compile the served path's programs at real widths for a described TPU
+v5e, with no chip attached: the TPU compiler refuses here what it would
+refuse on the chip (misaligned kernel blocks, programs that do not fit).
+
+Nothing runs, so these tests say nothing about results or times.  The
+topology is described inside a fixture, never at import: only one process
+at a time may load the TPU library, and every test worker imports this
+file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.data import synthetic as syn
+from repro.kernels import interaction, ops
+from repro.models import recsys
+from repro.serve.models import served_forward
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("bucket", [1, 256])
+def test_dlrm_rmc1_served_forward_compiles(one_chip, bucket):
+    cfg = configs.get("dlrm-rmc1").config
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: recsys.init(k, cfg), jax.random.PRNGKey(0)))
+    assert params["tables"].shape == (10, 1_000_000, 32)
+    batch = _on(one_chip, syn.recsys_specs(cfg, bucket, with_label=False))
+    compiled = served_forward("tpu").lower(params, cfg, batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 10 * 1_000_000 * 32 * 4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    # switching between bucket programs with this on halted a v5e
+    assert "cross_program_prefetch" not in compiled.as_text()
+
+
+def test_dot_interaction_kernel_compiles(one_chip):
+    feats = jax.ShapeDtypeStruct((1024, 11, 128), jnp.float32,
+                                 sharding=one_chip)
+    compiled = jax.jit(interaction.dot_interaction).lower(feats).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", [8, 1024])
+def test_embedding_bag_kernel_compiles(one_chip, batch):
+    table = jax.ShapeDtypeStruct((1_000_000, 128), jnp.float32,
+                                 sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch, 80), jnp.int32, sharding=one_chip)
+    compiled = ops.embedding_bag.lower(table, idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -137,3 +137,28 @@ def test_tune_with_accel_improves_or_matches():
 def test_device_model_monotone_latency():
     for b1, b2 in [(1, 16), (16, 256), (256, 4096)]:
         assert CPU.latency(b2) > CPU.latency(b1)
+
+
+def test_tune_pool_workers_import_no_jax():
+    """``tune(workers=N)`` spawns fresh interpreters that import the
+    scheduler's module chain to unpickle their work.  That chain must not
+    import JAX: on an accelerator host a worker that loaded it would
+    contend with the parent for the chip."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import sys, repro.core, repro.core.scheduler\n"
+            "bad = sorted(m for m in sys.modules if m.split('.')[0] == 'jax')\n"
+            "assert not bad, bad\n")
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         env=dict(os.environ, PYTHONPATH=src))
+    assert res.returncode == 0, res.stderr
+
+
+def test_accelerator_model_rejects_unknown_kind():
+    from repro.core.latency_model import accelerator_model
+    with pytest.raises(ValueError, match="unknown accelerator kind"):
+        accelerator_model(None, "v6e")

@@ -22,7 +22,7 @@ def _tol(dtype):
 def test_embedding_bag_kernel(vocab, batch, hot, dim, dtype):
     table = jax.random.normal(KEY, (vocab, dim)).astype(dtype)
     idx = jax.random.randint(KEY, (batch, hot), 0, vocab)
-    got = ops.embedding_bag(table, idx, use_pallas=True, interpret=True)
+    got = ops.embedding_bag(table, idx, interpret=True)
     # oracle in f32 (the kernel accumulates f32; a bf16-accumulating oracle
     # would itself carry ~H·2⁻⁸ drift)
     want = ref.embedding_bag(table.astype(jnp.float32), idx).astype(dtype)
@@ -34,7 +34,7 @@ def test_embedding_bag_kernel(vocab, batch, hot, dim, dtype):
 def test_embedding_bag_kernel_modes(mode):
     table = jax.random.normal(KEY, (50, 128))
     idx = jax.random.randint(KEY, (8, 5), 0, 50)
-    got = ops.embedding_bag(table, idx, mode=mode, use_pallas=True, interpret=True)
+    got = ops.embedding_bag(table, idx, mode=mode, interpret=True)
     # the kernel accumulates with Kahan compensation, so hold it to the
     # f64-exact pooled value (up to f32 ulps of the row magnitudes) — an
     # f32 oracle with atol=0 would demand bitwise-matching *rounding order*,
@@ -53,7 +53,7 @@ def test_embedding_bag_kernel_modes(mode):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_dot_interaction_kernel(batch, fields, dim, dtype):
     feats = (jax.random.normal(KEY, (batch, fields, dim)) / dim ** 0.5).astype(dtype)
-    got = ops.dot_interaction(feats, use_pallas=True, interpret=True)
+    got = ops.dot_interaction(feats, interpret=True)
     want = ref.dot_interaction_packed(feats)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -66,7 +66,7 @@ def test_cin_kernel(batch, f, h, hn, dim):
     x0 = jax.random.normal(KEY, (batch, f, dim)) / dim ** 0.5
     xk = jax.random.normal(jax.random.fold_in(KEY, 1), (batch, h, dim)) / dim ** 0.5
     w = jax.random.normal(jax.random.fold_in(KEY, 2), (h * f, hn))
-    got = ops.cin_layer(x0, xk, w, use_pallas=True, interpret=True)
+    got = ops.cin_layer(x0, xk, w, interpret=True)
     want = ref.cin_layer(x0, xk, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -81,7 +81,7 @@ def test_flash_decode_kernel(b, hq, hkv, d, t, dtype):
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (b, t, hkv, d)).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (b, t, hkv, d)).astype(dtype)
     pos = jax.random.randint(KEY, (b,), 1, t + 1)
-    got = ops.decode_attention(q, k, v, pos, use_pallas=True, interpret=True)
+    got = ops.decode_attention(q, k, v, pos, interpret=True)
     want = ref.decode_attention(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -96,7 +96,6 @@ def test_flash_decode_pos_zero_vs_one():
     q = jax.random.normal(KEY, (b, hq, d))
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (b, t, hkv, d))
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (b, t, hkv, d))
-    got = ops.decode_attention(q, k, v, jnp.array([1]), use_pallas=True,
-                               interpret=True)
+    got = ops.decode_attention(q, k, v, jnp.array([1]), interpret=True)
     np.testing.assert_allclose(np.asarray(got[0, 0]), np.asarray(v[0, 0, 0]),
                                rtol=1e-5, atol=1e-5)

@@ -163,6 +163,14 @@ def test_build_model_rejects_unknown_spec():
     assert out.shape == (1,)
 
 
+def test_supervisor_pins_stand_in_workers_to_cpu(monkeypatch):
+    """Stand-ins model CPU server nodes: the worker's platform is set on
+    purpose, never inherited from the parent, which on an accelerator
+    host may hold the chip."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert WorkerSupervisor()._env()["JAX_PLATFORMS"] == "cpu"
+
+
 # ------------------------------------------------------- worker lifecycle
 
 
