@@ -41,6 +41,7 @@ SUITES = [
     ("cache_offload", "benchmarks.cache_offload"),
     ("slo_diagnosis", "benchmarks.slo_diagnosis"),
     ("roofline_report", "benchmarks.roofline_report"),
+    ("recorder_cost", "benchmarks.recorder_cost"),
 ]
 
 

@@ -189,11 +189,27 @@ def forward(params, cfg: RecConfig, batch: dict) -> jax.Array:
     if cfg.n_dense:
         dense_out = batch["dense"].astype(cfg.jdtype)
         if cfg.dense_fc:
-            dense_out = mlp(params["dense_mlp"], dense_out, act="relu",
-                            final_act="relu")
+            with jax.named_scope("bottom_mlp"):
+                dense_out = mlp(params["dense_mlp"], dense_out, act="relu",
+                                final_act="relu")
 
-    emb = _sparse_pooled(params, cfg, batch["sparse"]) if cfg.n_tables else None
+    emb = None
+    if cfg.n_tables:
+        with jax.named_scope("embedding_gather"):
+            emb = _sparse_pooled(params, cfg, batch["sparse"])
 
+    if cfg.interaction == "cin":                      # carries its own heads
+        return _xdeepfm_forward(params, cfg, emb, batch)
+    with jax.named_scope("interaction"):
+        z = _interact(params, cfg, emb, dense_out, batch)
+    with jax.named_scope("top_mlp"):
+        outs = [mlp(pp, z, act="relu") for pp in params["predict"]]
+    out = jnp.concatenate(outs, axis=-1) if cfg.n_tasks > 1 else outs[0]
+    return out[..., 0] if cfg.n_tasks == 1 else out
+
+
+def _interact(params, cfg: RecConfig, emb, dense_out, batch):
+    """The feature interaction: the top MLP's input."""
     it = cfg.interaction
     if it == "concat":
         parts = [] if dense_out is None else [dense_out]
@@ -212,8 +228,6 @@ def forward(params, cfg: RecConfig, batch: dict) -> jax.Array:
         z = ix.fm_interaction(emb)
         if dense_out is not None:
             z = jnp.concatenate([z, dense_out], axis=-1)
-    elif it == "cin":
-        return _xdeepfm_forward(params, cfg, emb, batch)
     elif it == "self-attn":
         x = emb
         dim = cfg.embed_dim
@@ -260,10 +274,7 @@ def forward(params, cfg: RecConfig, batch: dict) -> jax.Array:
         z = h[:, -1] * tgt                                            # elementwise match
     else:
         raise ValueError(it)
-
-    outs = [mlp(pp, z, act="relu") for pp in params["predict"]]
-    out = jnp.concatenate(outs, axis=-1) if cfg.n_tasks > 1 else outs[0]
-    return out[..., 0] if cfg.n_tasks == 1 else out
+    return z
 
 
 def _xdeepfm_forward(params, cfg, emb, batch):

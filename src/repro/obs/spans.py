@@ -2,8 +2,7 @@
 
 A span is a small set of stage timestamps on the shared trace timeline::
 
-    enqueued -> routed -> submitted -> batch_formed -> exec_start
-             -> exec_done -> completed
+    enqueued -> routed -> exec_start -> completed
 
 plus annotations (re-route count, RPC-retry stall seconds, shed flag).
 Rather than one object per query, :class:`SpanTable` stores the fleet's
@@ -16,9 +15,9 @@ How each engine fills the stamps:
     (want_starts=True)`` returns each query's first executor dispatch
     (departure minus service per request, min over the query's requests),
     so ``exec_start`` needs no event loop;
-  * **live** — ``ServingRuntime`` workers stamp ``QueryRecord.t_started``
-    when they pick a request up; the backend converts wall clock back to
-    trace time;
+  * **live** — ``ServingRuntime`` sets ``QueryRecord.t_started`` to the
+    query's first pick-up in its request log; the backend converts wall
+    clock back to trace time;
   * **remote** — the worker stamps the same way and the poll reply's
     completion rows carry two extra columns, so worker-side timings
     survive the socket hop.
@@ -36,7 +35,7 @@ percentile-by-percentile:
   ``dispatch`` = released − routed − retry_s − cache_s
                                         (submit + batch formation)
   ``queueing`` = exec_start − released  (executor queue depth)
-  ``service``  = exec_done − exec_start (device/model execution)
+  ``service``  = completed − exec_start (device/model execution)
 
 plus ``boot_wait`` (admission deferred behind a booting fleet — zero
 under the current driver, which drops instead of deferring; the column
@@ -54,8 +53,7 @@ import numpy as np
 __all__ = ["SpanTable", "QuerySpan", "STAGES", "COMPONENTS"]
 
 # canonical stage stamps, in order
-STAGES = ("enqueued", "routed", "submitted", "batch_formed",
-          "exec_start", "exec_done", "completed")
+STAGES = ("enqueued", "routed", "exec_start", "completed")
 
 # additive latency components, in stage order
 COMPONENTS = ("reroute", "retry", "cache", "dispatch", "queueing",
@@ -189,16 +187,10 @@ class SpanTable:
 
     def span(self, index: int) -> QuerySpan:
         comp = {k: float(v[index]) for k, v in self.components().items()}
-        rel = self.t_released[index]
-        if np.isnan(rel):
-            rel = self.t_routed[index]
         stages = {
             "enqueued": float(self.t_enqueued[index]),
             "routed": float(self.t_routed[index]),
-            "submitted": float(self.t_routed[index]),
-            "batch_formed": float(rel),
             "exec_start": float(self.t_exec_start[index]),
-            "exec_done": float(self.t_done[index]),
             "completed": float(self.t_done[index]),
         }
         return QuerySpan(index=int(index), stages=stages, components=comp,

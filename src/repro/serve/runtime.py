@@ -8,6 +8,22 @@ the batch-size knob using the measured p95 over a sliding window — the
 
 This runs the actual JAX models on this host; the simulator covers at-scale
 what one machine cannot.
+
+Each request is a row of the runtime's request log
+(``serve.recorder.RequestLog``: ids and six monotonic stamps, read through
+``ServingRuntime.request_log``) and, while a profiler session records, a
+set of spans on the device trace's clock, each carrying the request's
+``rid``, ``qid``, ``bucket`` and ``rows`` where known:
+
+  ``runtime.dequeue``      a worker blocked on the queue (idle)
+  ``runtime.pad``          ``pad_batch`` to the request's bucket
+  ``runtime.dispatch``     the ``apply_fn`` call: enqueue, host-to-device copy
+  ``runtime.device_wait``  ``block_until_ready`` on its result
+  ``runtime.complete``     the bookkeeping under the lock
+  ``feeder.release``       ``PacedFeeder`` releasing one query (``qid``)
+
+The runtime also installs the process's garbage-collection pause counter
+(``ServingRuntime.pauses``).
 """
 from __future__ import annotations
 
@@ -20,11 +36,14 @@ from typing import Callable
 import numpy as np
 
 from repro.core.scheduler import BATCH_LADDER, THRESHOLD_LADDER
+from repro.serve import recorder
 from repro.serve.batching import bucket_for, pad_batch
+from repro.serve.recorder import span
 
 
 @dataclasses.dataclass
 class _Request:
+    rid: int                  # its row of the request log
     qid: int
     batch: dict
     size: int
@@ -36,8 +55,8 @@ class QueryRecord:
     size: int
     t_arrival: float
     t_done: float = 0.0
-    # wall instant a worker first picked one of the query's requests up —
-    # the span layer's exec_start stamp; 0.0 until then
+    # the query's first pick-up in the request log — the span layer's
+    # exec_start stamp; 0.0 until one of its requests has finished
     t_started: float = 0.0
     error: str | None = None   # first apply_fn failure among the requests
 
@@ -63,8 +82,11 @@ class ServingRuntime:
         self._fresh_done: list[QueryRecord] = []
         self._done_log: list[QueryRecord] = []
         self._stop = threading.Event()
-        self._workers = [threading.Thread(target=self._worker, daemon=True)
-                         for _ in range(n_workers)]
+        self._log = recorder.request_log()
+        recorder.pauses()
+        self._workers = [threading.Thread(target=self._worker, args=(w,),
+                                          daemon=True)
+                         for w in range(n_workers)]
         for w in self._workers:
             w.start()
 
@@ -82,13 +104,15 @@ class ServingRuntime:
             raise ValueError(f"query size must be >= 1, got {size}")
         bsz = min(self.batch_size, self.max_bucket)
         n_req = -(-size // bsz)
+        now = time.monotonic()
         with self._lock:
-            self._records[qid] = QueryRecord(qid, size, time.monotonic())
+            self._records[qid] = QueryRecord(qid, size, now)
             self._outstanding[qid] = n_req
         for i in range(n_req):
             lo, hi = i * bsz, min((i + 1) * bsz, size)
             sub = {k: v[lo:hi] for k, v in batch.items()}
-            self._q.put(_Request(qid, sub, hi - lo))
+            rid = self._log.open(qid, i, hi - lo, now)
+            self._q.put(_Request(rid, qid, sub, hi - lo))
 
     def drain(self, timeout: float = 60.0) -> None:
         t0 = time.monotonic()
@@ -100,11 +124,22 @@ class ServingRuntime:
         raise TimeoutError("serving queue did not drain")
 
     def shutdown(self) -> None:
+        """Stop the workers; requests still queued are abandoned, and their
+        log rows closed as never picked up."""
         self._stop.set()
         for _ in self._workers:
             self._q.put(None)
         for w in self._workers:
             w.join(timeout=5)
+        nan = float("nan")
+        while True:
+            try:
+                req = self._q.get_nowait()
+            except queue.Empty:
+                return
+            if req is not None:
+                self._log.close(req.rid, -1, -1, nan, nan, nan, nan,
+                                time.monotonic())
 
     def completed(self) -> list[QueryRecord]:
         with self._lock:
@@ -147,47 +182,74 @@ class ServingRuntime:
         with self._lock:
             return self._done_log[start:]
 
+    def request_log(self, start: int = 0
+                    ) -> tuple[dict[str, np.ndarray], int]:
+        """Finished requests from position ``start`` of the runtime's
+        request log, as numpy columns (``RequestLog.FIELDS`` and ``STAMPS``,
+        monotonic seconds), and the cursor to pass next — an O(new) read
+        like ``completed_log``."""
+        return self._log.rows(start)
+
+    @staticmethod
+    def pauses() -> dict[str, list]:
+        """The process's garbage collections so far, per generation:
+        ``count``, ``total_s`` and ``max_s``; each one's interval is in
+        ``recorder.pauses().rows()``."""
+        return recorder.pauses().totals()
+
     def percentile_ms(self, p: float) -> float:
         lats = [r.latency_ms for r in self.completed()]
         return float(np.percentile(lats, p)) if lats else 0.0
 
     # ------------------------------------------------------------- worker
 
-    def _worker(self) -> None:
+    def _worker(self, w: int) -> None:
         import jax
+        nan = float("nan")
         while not self._stop.is_set():
-            req = self._q.get()
+            with span("runtime.dequeue"):
+                req = self._q.get()
+            t_pick = time.monotonic()
             if req is None:
                 return
-            # first-dispatch stamp, lockless: the record was inserted
-            # before the request was enqueued, and a two-worker race on
-            # the first two requests differs by a queue handoff at most
-            rec0 = self._records.get(req.qid)
-            if rec0 is not None and rec0.t_started == 0.0:
-                rec0.t_started = time.monotonic()
+            bucket = bucket_for(req.size, self.max_bucket)
+            ids = {"rid": req.rid, "qid": req.qid, "bucket": bucket,
+                   "rows": req.size}
+            t_pad = t_disp = t_ready = nan
             err = None
             try:
-                bucket = bucket_for(req.size, self.max_bucket)
-                padded = pad_batch(req.batch, bucket)
-                jax.block_until_ready(self._apply(padded))
+                with span("runtime.pad", ids):
+                    padded = pad_batch(req.batch, bucket)
+                t_pad = time.monotonic()
+                with span("runtime.dispatch", ids):
+                    out = self._apply(padded)
+                t_disp = time.monotonic()
+                with span("runtime.device_wait", ids):
+                    jax.block_until_ready(out)
+                t_ready = time.monotonic()
             except Exception as e:
                 # an apply_fn failure must not kill the worker thread or
                 # strand the query's _outstanding entry (which would
                 # deadlock drain()) — complete the query, carry the error
                 err = f"{type(e).__name__}: {e}"
             finally:
-                now = time.monotonic()
-                with self._lock:
-                    rec = self._records[req.qid]
-                    if err is not None and rec.error is None:
-                        rec.error = err
-                    self._outstanding[req.qid] -= 1
-                    if self._outstanding[req.qid] == 0:
-                        del self._outstanding[req.qid]
-                        rec.t_done = now
-                        self._n_done += 1
-                        self._fresh_done.append(rec)
-                        self._done_log.append(rec)
+                with span("runtime.complete", ids):
+                    now = time.monotonic()
+                    with self._lock:
+                        rec = self._records[req.qid]
+                        if err is not None and rec.error is None:
+                            rec.error = err
+                        if rec.t_started == 0.0 or t_pick < rec.t_started:
+                            rec.t_started = t_pick
+                        self._outstanding[req.qid] -= 1
+                        if self._outstanding[req.qid] == 0:
+                            del self._outstanding[req.qid]
+                            rec.t_done = now
+                            self._n_done += 1
+                            self._fresh_done.append(rec)
+                            self._done_log.append(rec)
+                    self._log.close(req.rid, bucket, w, t_pick, t_pad,
+                                    t_disp, t_ready, now)
 
 
 class PacedFeeder:
@@ -245,7 +307,8 @@ class PacedFeeder:
                 delay = self._wall_of(t) - time.monotonic()
                 if delay > 0 and self._closing.wait(delay):
                     continue               # woken by stop(), not arrival
-                self._release(qid, size, mid)
+                with span("feeder.release", {"qid": qid}):
+                    self._release(qid, size, mid)
             except Exception as e:         # keep feeding; query → dropped
                 if self._on_error is not None:
                     self._on_error(qid, e)

@@ -7,7 +7,9 @@ topology is described inside a fixture, never at import: only one process
 at a time may load the TPU library, and every test worker imports this
 file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +65,34 @@ def test_dlrm_rmc1_served_forward_compiles(one_chip, bucket):
         < V5E_HBM_BYTES
     # switching between bucket programs with this on halted a v5e
     assert "cross_program_prefetch" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_dlrm_rmc1_named_scopes_change_no_program(one_chip, monkeypatch,
+                                                  bucket):
+    """The forward's named scopes (gather, bottom MLP, interaction, top
+    MLP) reach the TPU program's metadata and nothing else."""
+    cfg = configs.get("dlrm-rmc1").config
+
+    def compiled() -> str:
+        jax.clear_caches()
+        params = _on(one_chip, jax.eval_shape(
+            lambda k: recsys.init(k, cfg), jax.random.PRNGKey(0)))
+        batch = _on(one_chip, syn.recsys_specs(cfg, bucket, with_label=False))
+        return served_forward("tpu").lower(params, cfg,
+                                           batch).compile().as_text()
+
+    def program(hlo: str) -> str:
+        body = hlo.split("\nFileNames\n")[0]
+        return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    scoped = compiled()
+    assert "jit(forward)/embedding_gather/" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert "embedding_gather" not in plain
+    assert program(scoped) == program(plain)
 
 
 def test_dot_interaction_kernel_compiles(one_chip):

@@ -1,7 +1,9 @@
 """Per-architecture smoke tests (reduced configs, one forward/train step on
 CPU, asserting shapes + finite outputs) — all 10 assigned archs + the 8
 DeepRecInfra paper models."""
+import contextlib
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +13,7 @@ import pytest
 from repro import configs
 from repro.data import synthetic as syn
 from repro.models import gnn, lm, recsys
+from repro.serve.models import served_forward
 
 KEY = jax.random.PRNGKey(0)
 
@@ -61,6 +64,41 @@ def test_recsys_bulk_forward_matches_direct():
     chunked = recsys.bulk_forward(params, cfg, batch, chunk=8)
     np.testing.assert_allclose(np.asarray(direct), np.asarray(chunked),
                                rtol=1e-5, atol=1e-5)
+
+
+RECSYS_SCOPES = ("embedding_gather", "bottom_mlp", "interaction", "top_mlp")
+
+
+def without_metadata(hlo: str) -> str:
+    """Compiled HLO text less each instruction's metadata and the source
+    locations those point into."""
+    body = hlo.split("\nFileNames\n")[0]
+    return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+
+@pytest.mark.parametrize("bucket", [1, 64])
+def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket):
+    """The served forward's HLO names the gather, the bottom MLP, the
+    interaction and the top MLP; without the scopes XLA compiles the same
+    program."""
+    cfg = configs.get("dlrm-rmc1").smoke_config
+
+    def compiled() -> str:
+        jax.clear_caches()
+        params = jax.eval_shape(lambda k: recsys.init(k, cfg), KEY)
+        batch = syn.recsys_specs(cfg, bucket, with_label=False)
+        return served_forward("cpu").lower(params, cfg,
+                                           batch).compile().as_text()
+
+    scoped = compiled()
+    op_names = re.findall(r'op_name="([^"]*)"', scoped)
+    for scope in RECSYS_SCOPES:
+        assert any(f"jit(forward)/{scope}/" in n for n in op_names), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert not any(f"/{scope}/" in plain for scope in RECSYS_SCOPES)
+    assert without_metadata(scoped) == without_metadata(plain)
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)

@@ -1,4 +1,6 @@
-"""Live serving runtime: split/execute/complete, bucketing, online control."""
+"""Live serving runtime: split/execute/complete, bucketing, online control,
+the request log and the pause counter."""
+import gc
 import time
 
 import jax
@@ -6,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.serve import recorder
 from repro.serve.batching import bucket_for, pad_batch, slice_result
 from repro.serve.runtime import (OffloadController, OnlineController,
                                  ServingRuntime)
@@ -259,3 +262,169 @@ def test_offload_controller_snaps_and_clamps():
     assert ctl.step(500.0, 10.0, 0.0) == 1              # clamped at floor
     ctl2 = OffloadController(sla_ms=100.0, threshold=1001)
     assert ctl2.step(500.0, 0.0, 10.0) == 1001          # clamped at top
+
+
+# ------------------------------------------------ request log and pauses
+
+
+def _served(rt, sizes):
+    """Submit one query per size, wait, and return the runtime's log."""
+    for qid, size in enumerate(sizes):
+        rt.submit(qid, {"x": np.ones((size, 4), np.float32)}, size)
+    rt.drain(timeout=60)
+    rows, _ = rt.request_log()
+    return rows
+
+
+def test_request_log_has_one_row_per_request():
+    rt = _runtime(batch_size=16)
+    try:
+        rows = _served(rt, [40, 3, 16])       # 16+16+8, 3, 16
+    finally:
+        rt.shutdown()
+    got = sorted(zip(rows["qid"], rows["part"], rows["rows"],
+                     rows["bucket"]))
+    assert got == [(0, 0, 16, 16), (0, 1, 16, 16), (0, 2, 8, 8),
+                   (1, 0, 3, 4), (2, 0, 16, 16)]
+    assert set(rows["worker"]) <= {0, 1}
+    assert len(set(rows["rid"])) == 5
+
+
+def test_request_log_stamps_are_ordered_and_start_the_query():
+    rt = _runtime(batch_size=8)
+    try:
+        rows = _served(rt, [30, 5, 17, 1])
+        recs = {r.qid: r for r in rt.completed()}
+    finally:
+        rt.shutdown()
+    t = np.stack([rows[k] for k in recorder.RequestLog.STAMPS])
+    assert np.all(np.isfinite(t))
+    assert np.all(np.diff(t, axis=0) >= 0)    # enqueue ≤ pickup ≤ … ≤ done
+    for qid, rec in recs.items():
+        mine = rows["qid"] == qid
+        assert rec.t_started == rows["pickup"][mine].min()
+        assert rec.t_done == rows["done"][mine].max()
+        assert np.all(rows["enqueue"][mine] == rec.t_arrival)
+
+
+def test_request_log_cursor_reads_each_row_once():
+    rt = _runtime(batch_size=4)
+    try:
+        first = _served(rt, [9])              # 3 requests
+        _, cur = rt.request_log()
+        _served(rt, [5])                      # 2 more
+        fresh, nxt = rt.request_log(cur)
+    finally:
+        rt.shutdown()
+    assert len(first["rid"]) == 3 and len(fresh["rid"]) == 2
+    assert set(fresh["rid"]).isdisjoint(first["rid"])
+    assert nxt > cur and rt.request_log(nxt)[0]["rid"].size == 0
+
+
+def test_request_log_holds_a_failed_request():
+    def apply_fn(batch):
+        raise RuntimeError("boom")
+
+    rt = ServingRuntime(apply_fn, n_workers=1, batch_size=8)
+    try:
+        rows = _served(rt, [3])
+    finally:
+        rt.shutdown()
+    assert len(rows["rid"]) == 1
+    assert np.isfinite(rows["pad"][0]) and np.isnan(rows["dispatch"][0])
+    assert rows["done"][0] >= rows["pickup"][0]
+
+
+def test_shutdown_closes_the_rows_of_abandoned_requests():
+    """Requests still queued at shutdown are never picked up; their rows
+    are closed as such, so they hold no reader's cursor."""
+    import threading
+    go = threading.Event()
+
+    def apply_fn(batch):
+        go.wait(timeout=10)
+        return batch["x"]
+
+    rt = ServingRuntime(apply_fn, n_workers=1, batch_size=1)
+    rt.submit(0, {"x": np.ones((5, 4), np.float32)}, 5)
+    time.sleep(0.05)                          # the worker holds request 0
+    rt._stop.set()
+    go.set()
+    rt.shutdown()
+    rows, _ = rt.request_log()
+    assert len(rows["rid"]) == 5
+    assert np.isfinite(rows["done"]).all()
+    picked = np.isfinite(rows["pickup"])
+    assert picked[0] and not picked[1:].any()
+    assert (rows["bucket"][~picked] == -1).all()
+
+
+def test_request_log_ring_keeps_the_newest_rows():
+    log = recorder.RequestLog(capacity=4)
+    rids = [log.open(q, 0, 1, float(q)) for q in range(6)]
+    for r in rids:
+        log.close(r, 1, 0, 1.0, 2.0, 3.0, 4.0, 5.0)
+    rows, cur = log.rows(0)
+    assert rows["rid"].tolist() == [2, 3, 4, 5] and cur == 6
+    # a request still running holds the cursor, and the rows after it
+    late = log.open(9, 0, 1, 9.0)
+    after = log.open(10, 0, 1, 9.0)
+    log.close(after, 1, 0, 1.0, 2.0, 3.0, 4.0, 5.0)
+    rows, cur = log.rows(6)
+    assert len(rows["rid"]) == 0 and cur == late
+    log.close(late, 1, 0, 1.0, 2.0, 3.0, 4.0, 5.0)
+    rows, cur = log.rows(6)
+    assert rows["rid"].tolist() == [late, after] and cur == 8
+    # a row the ring has wrapped past is not written over by its close
+    log.close(rids[0], 9, 9, 0.0, 0.0, 0.0, 0.0, 0.0)
+    assert log.rows(4)[0]["bucket"].tolist() == [1] * 4
+
+
+def test_each_runtime_has_its_own_request_log():
+    a, b = _runtime(batch_size=4), _runtime(batch_size=4)
+    try:
+        ra, rb = _served(a, [9]), _served(b, [5])   # 3 and 2 requests
+    finally:
+        a.shutdown()
+        b.shutdown()
+    assert ra["rid"].tolist() == [0, 1, 2] and rb["rid"].tolist() == [0, 1]
+    logs = recorder.recent_logs()
+    assert logs[-2:] == [a._log, b._log]
+
+
+def test_request_log_adds_no_object_per_request():
+    """Serving 2,000 requests leaves no more garbage-collected objects
+    behind than serving 100: the log is numpy columns written in place."""
+    def grown(sizes):
+        rt = ServingRuntime(lambda b: b["x"], n_workers=1, batch_size=1)
+        try:
+            _served(rt, [1])                  # first-call work
+            gc.collect()
+            before = len(gc.get_objects())
+            _served(rt, sizes)
+            gc.collect()
+            return len(gc.get_objects()) - before
+        finally:
+            rt.shutdown()
+    small = grown([5] * 20)                   # 20 queries, 100 requests
+    large = grown([100] * 20)                 # 20 queries, 2,000 requests
+    assert large - small < 200            # one object per request: 1,900
+
+
+def test_pause_counter_counts_a_forced_collection():
+    _runtime().shutdown()                     # installs the hook
+    log = recorder.pauses()
+    assert gc.callbacks.count(log) == 1
+    before = ServingRuntime.pauses()
+    _, cur = log.rows()
+    t0 = time.monotonic()
+    gc.collect()
+    t1 = time.monotonic()
+    after = ServingRuntime.pauses()
+    assert after["count"][2] == before["count"][2] + 1
+    assert after["total_s"][2] > before["total_s"][2]
+    assert after["max_s"][2] >= after["total_s"][2] - before["total_s"][2]
+    rows, _ = log.rows(cur)
+    full = rows["generation"] == 2
+    assert full.sum() == 1
+    assert t0 <= rows["start"][full][0] <= rows["end"][full][0] <= t1
