@@ -22,6 +22,70 @@ def init_table(rng, vocab: int, dim: int, *, dtype=jnp.float32, scale: float | N
     return (jax.random.normal(rng, (vocab, dim)) * scale).astype(dtype)
 
 
+# ---------------------------------------------------------- 128-lane packing
+
+LANES = 128
+
+
+def pack_rows(table: jax.Array) -> jax.Array:
+    """``(..., V, D)`` → ``(..., ceil(V/p), 128)`` with ``p = 128 // D``
+    rows of the table side by side in each packed row: a row-major
+    reshape, zero rows appended where ``V % p``.  A table whose width is
+    128 or more, or does not divide 128, is returned as it is.
+
+    Why: on the TPU a ``(V, D)`` float32 table with ``D < 128`` is laid out
+    with the row axis minor (``{0,1:T(8,128)}``), so as not to pad ``D`` to
+    128 lanes.  Each (8, 128) tile then holds 8 values of 128 different
+    rows, one row lies across ``D / 8`` tiles (four 4-KB tiles for
+    ``D = 32``), and a gather reads all of them.  Packed, the table is
+    row-major with no padding and each lookup reads one contiguous
+    512-byte row."""
+    *lead, v, d = table.shape
+    if d >= LANES or LANES % d:
+        return table
+    p = LANES // d
+    if v % p:
+        table = jnp.pad(table, [(0, 0)] * len(lead) + [(0, -v % p), (0, 0)])
+    return table.reshape(*lead, -1, LANES)
+
+
+def take_rows(tables: jax.Array, idx: jax.Array, dim: int,
+              vocab: int) -> jax.Array:
+    """Rows of width ``dim`` from a stack of ``vocab``-row tables, stored as
+    ``(F, vocab, dim)`` or packed by ``pack_rows`` to ``(F, Vp, 128)``
+    (told apart by the stored width): ``idx (..., F, H)`` with the ids of
+    table ``f`` at ``[..., f, :]`` → ``(..., F, H, dim)``.
+
+    Plain tables are read by ``jnp.take`` per table.  Packed ones are read
+    by one gather over the stack seen as one ``(F * Vp, 128)`` table, at
+    packed row ``f * Vp + id // p``; compare and select then keep lanes
+    ``(id % p) * dim`` to ``+ dim``, so every value is the stored one, bit
+    for bit (no one-hot contraction, which the TPU would run in bfloat16).
+    Either way ids outside ``[0, vocab)`` read what ``jnp.take`` reads: an
+    id in ``[-vocab, 0)`` counts from the end, any other reads NaN (never
+    the zero rows ``pack_rows`` appends).
+
+    Why one flat gather and not one batched over the packed tables: on a
+    TPU v5e that batched gather, whose (table, row) index XLA packs into
+    one word, halted the core at its first call in every fresh process;
+    the flat gather ran."""
+    if tables.shape[-1] == dim:
+        return jax.vmap(lambda t, i: jnp.take(t, i, axis=0),
+                        in_axes=(0, 1), out_axes=1)(tables, idx)
+    f, vp, lanes = tables.shape
+    p = lanes // dim
+    idx = jnp.where(idx < 0, idx + vocab, idx)
+    row = jnp.where((idx >= 0) & (idx < vocab),
+                    idx // p + vp * jnp.arange(f)[:, None], f * vp)
+    packed = jnp.take(tables.reshape(f * vp, lanes), row, axis=0)
+    lane = idx % p
+    rows = packed[..., :dim]
+    for j in range(1, p):
+        rows = jnp.where((lane == j)[..., None],
+                         packed[..., j * dim:(j + 1) * dim], rows)
+    return rows
+
+
 # ---------------------------------------------------------------- fixed-hotness
 
 

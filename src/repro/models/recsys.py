@@ -170,9 +170,8 @@ def _interaction_dim(cfg: RecConfig) -> int:
 
 def _sparse_pooled(params, cfg: RecConfig, sparse: jax.Array) -> jax.Array:
     """sparse (B, F, H) → (B, F, D) per-table pooled embeddings."""
-    tables = params["tables"]                                        # (F, V, D)
-    rows = jax.vmap(lambda t, i: jnp.take(t, i, axis=0),
-                    in_axes=(0, 1), out_axes=1)(tables, sparse)      # (B, F, H, D)
+    rows = emb_lib.take_rows(params["tables"], sparse, cfg.embed_dim,
+                             cfg.vocab)                              # (B, F, H, D)
     if cfg.pooling == "sum":
         return rows.sum(axis=2)
     if cfg.pooling == "mean":

@@ -19,8 +19,8 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs
 from repro.data import synthetic as syn
 from repro.kernels import interaction, ops
-from repro.models import recsys
-from repro.serve.models import served_forward
+from repro.layers import embedding as emb_lib
+from repro.serve.models import served_forward, served_param_shapes
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
@@ -51,20 +51,64 @@ def _on(sharding, tree):
         tree)
 
 
-@pytest.mark.parametrize("bucket", [1, 256])
+def _bitpacked_gathers(hlo: str) -> list[str]:
+    """Instructions of the gather scope that pack a (table, row) index into
+    one word, whatever the order of their attributes."""
+    return [line for line in hlo.splitlines()
+            if "GatherScatterIndicesBitpacked" in line
+            and "/embedding_gather/" in line]
+
+
+@pytest.mark.parametrize("bucket", [1, 64, 256])
 def test_dlrm_rmc1_served_forward_compiles(one_chip, bucket):
+    """The served forward on the params as ``recsys_model`` holds them:
+    tables packed four 32-wide rows to a 128-lane row reach the program
+    row-major and unpadded, so each lookup reads one contiguous row, not a
+    row-minor table's four tiles."""
     cfg = configs.get("dlrm-rmc1").config
-    params = _on(one_chip, jax.eval_shape(
-        lambda k: recsys.init(k, cfg), jax.random.PRNGKey(0)))
-    assert params["tables"].shape == (10, 1_000_000, 32)
+    params = _on(one_chip, served_param_shapes(cfg))
+    assert params["tables"].shape == (10, 250_000, 128)
     batch = _on(one_chip, syn.recsys_specs(cfg, bucket, with_label=False))
     compiled = served_forward("tpu").lower(params, cfg, batch).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 10 * 1_000_000 * 32 * 4
+    # 1,280,963,072 bytes of arguments: nothing padded to 128 lanes
+    assert mem.argument_size_in_bytes < 1.282e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+    hlo = compiled.as_text()
     # switching between bucket programs with this on halted a v5e
-    assert "cross_program_prefetch" not in compiled.as_text()
+    assert "cross_program_prefetch" not in hlo
+    assert re.search(r"%params__tables__\S* = f32\[10,250000,128\]"
+                     r"\{2,1,0:T\(8,128\)\} parameter", hlo)
+    assert not re.search(r"f32\[10,\d+,\d+\]\{1,2,0", hlo)
+    # one flat gather: the gather batched over the packed tables, whose
+    # (table, row) index XLA packs into one word, halted a v5e core
+    assert not _bitpacked_gathers(hlo)
+
+
+def test_the_bitpacked_gather_guard_catches_the_halting_form(one_chip):
+    """The lookup batched over the packed tables, the form that halted a
+    v5e core, compiles to a bit-packed index in the gather scope, and the
+    served flat lookup at the same shapes does not."""
+    cfg = configs.get("dlrm-rmc1").config
+    tables = _on(one_chip, served_param_shapes(cfg)["tables"])
+    ids = jax.ShapeDtypeStruct((64, 10, 80), jnp.int32, sharding=one_chip)
+
+    def batched(t, i):
+        with jax.named_scope("embedding_gather"):
+            return jax.vmap(lambda t, i: jnp.take(t, i // 4, axis=0),
+                            in_axes=(0, 1), out_axes=1)(t, i)
+
+    def flat(t, i):
+        with jax.named_scope("embedding_gather"):
+            return emb_lib.take_rows(t, i, cfg.embed_dim, cfg.vocab)
+
+    def hlo(fn):
+        return jax.jit(fn).lower(tables, ids).compile().as_text()
+
+    assert _bitpacked_gathers(hlo(batched))
+    assert not _bitpacked_gathers(hlo(flat))
 
 
 @pytest.mark.parametrize("bucket", [1, 64])
@@ -76,8 +120,7 @@ def test_dlrm_rmc1_named_scopes_change_no_program(one_chip, monkeypatch,
 
     def compiled() -> str:
         jax.clear_caches()
-        params = _on(one_chip, jax.eval_shape(
-            lambda k: recsys.init(k, cfg), jax.random.PRNGKey(0)))
+        params = _on(one_chip, served_param_shapes(cfg))
         batch = _on(one_chip, syn.recsys_specs(cfg, bucket, with_label=False))
         return served_forward("tpu").lower(params, cfg,
                                            batch).compile().as_text()

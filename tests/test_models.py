@@ -12,8 +12,10 @@ import pytest
 
 from repro import configs
 from repro.data import synthetic as syn
+from repro.layers import embedding as emb_lib
 from repro.models import gnn, lm, recsys
-from repro.serve.models import served_forward
+from repro.serve.models import (recsys_model, served_forward,
+                                served_param_shapes)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -85,7 +87,7 @@ def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket):
 
     def compiled() -> str:
         jax.clear_caches()
-        params = jax.eval_shape(lambda k: recsys.init(k, cfg), KEY)
+        params = served_param_shapes(cfg)
         batch = syn.recsys_specs(cfg, bucket, with_label=False)
         return served_forward("cpu").lower(params, cfg,
                                            batch).compile().as_text()
@@ -99,6 +101,65 @@ def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket):
     plain = compiled()
     assert not any(f"/{scope}/" in plain for scope in RECSYS_SCOPES)
     assert without_metadata(scoped) == without_metadata(plain)
+
+
+@pytest.mark.parametrize("pooling", ["sum", "mean"])
+@pytest.mark.parametrize("dim,vocab", [(8, 1000), (16, 1001), (32, 1003),
+                                       (64, 999), (10, 1000), (128, 1001)])
+def test_packed_lookup_matches_plain(dim, vocab, pooling):
+    """Tables packed into 128-lane rows give back what ``jnp.take`` gives
+    on the plain tables, bit for bit and NaN for NaN: ids 0 and V-1, a
+    vocabulary that is no multiple of the pack, and the ids ``jnp.take``
+    wraps (-1, -V) or fills (V, which lies in the appended zero rows,
+    -V-1, the int32 extremes).  A width that does not divide 128, or fills
+    it, stays as it is; the pooled lookup agrees to float32 rounding."""
+    n_tables, batch, hot = 3, 5, 7
+    table = jax.random.normal(KEY, (n_tables, vocab, dim))
+    packed = emb_lib.pack_rows(table)
+    p = 128 // dim if 128 % dim == 0 and dim < 128 else 1
+    want_shape = ((n_tables, -(-vocab // p), 128) if p > 1
+                  else (n_tables, vocab, dim))
+    assert packed.shape == want_shape
+    sparse = jax.random.randint(jax.random.PRNGKey(1),
+                                (batch, n_tables, hot), 0, vocab)
+    edge = [0, vocab - 1, -1, -vocab, vocab, -vocab - 1,
+            np.iinfo(np.int32).max, np.iinfo(np.int32).min]
+    sparse = sparse.at[0, :, :4].set(jnp.array(edge[:4])[None])
+    sparse = sparse.at[1, :, :4].set(jnp.array(edge[4:])[None])
+    plain = np.stack([np.asarray(jnp.take(table[t], sparse[:, t], axis=0))
+                      for t in range(n_tables)], axis=1)       # (B, F, H, D)
+    assert np.isnan(plain[1, :, :4]).all() and np.isfinite(plain[0]).all()
+    np.testing.assert_array_equal(
+        np.asarray(emb_lib.take_rows(packed, sparse, dim, vocab)), plain)
+    cfg = recsys.RecConfig(name="packed", interaction="concat",
+                           n_tables=n_tables, vocab=vocab, embed_dim=dim,
+                           hotness=hot, pooling=pooling)
+    np.testing.assert_allclose(
+        np.asarray(recsys._sparse_pooled({"tables": packed}, cfg, sparse)),
+        np.asarray(recsys._sparse_pooled({"tables": table}, cfg, sparse)),
+        rtol=1e-6, atol=1e-7)
+
+
+def test_served_model_packs_its_tables():
+    """The served DLRM-RMC1 holds its tables packed four rows to a 128-lane
+    row, with the values ``recsys.init`` draws, and serves what
+    ``recsys.forward`` computes on the unpacked tables."""
+    smoke = configs.get("dlrm-rmc1").smoke_config
+    cfg = dataclasses.replace(smoke, vocab=101, embed_dim=32,
+                              dense_fc=smoke.dense_fc[:-1] + (32,))
+    apply_fn, make_batch, params = recsys_model(cfg, seed=3, max_rows=16)
+    plain = jax.jit(recsys.init, static_argnums=1)(jax.random.PRNGKey(3),
+                                                   cfg)
+    assert plain["tables"].shape == (cfg.n_tables, 101, 32)
+    assert params["tables"].shape == (cfg.n_tables, 26, 128)
+    assert served_param_shapes(cfg)["tables"].shape == (cfg.n_tables, 26, 128)
+    np.testing.assert_array_equal(np.asarray(params["tables"]),
+                                  np.asarray(emb_lib.pack_rows(
+                                      plain["tables"])))
+    batch = make_batch(16, 0)
+    np.testing.assert_allclose(np.asarray(apply_fn(batch)),
+                               np.asarray(recsys.forward(plain, cfg, batch)),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)
