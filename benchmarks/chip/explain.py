@@ -167,7 +167,7 @@ def main(argv: list[str] | None = None) -> int:
     profiler = ProfilerCalls()
     rate = cell.mix["load_of_knee"] * cell.cfg["knee_qps"]
     sched = traffic.schedule(cell.mix, rate, args.seconds, args.seed)
-    s = harness.set_up(cell.cfg, cell.mix, args.seed,
+    s = harness.set_up(cell, args.seed,
                        harness.buckets_of(sched.sizes, cell.cfg["serving"]))
     setup_s = time.monotonic() - T_START
     args.out = args.out or tempfile.mkdtemp(prefix="explain-")
@@ -176,7 +176,8 @@ def main(argv: list[str] | None = None) -> int:
     w = harness.serve_window(s, sched, args.seconds, cell.mix["at_close"],
                              trace_dir=trace_dir)
     run = harness.Run(cell.cfg, cell.mix,
-                      peaks[jax.devices()[0].device_kind], setup_s, w, s.pool)
+                      peaks[jax.devices()[0].device_kind], setup_s, w, s.pool,
+                      cell.model)
     p = w.profile
     rows = program.requests(w)
     pauses = program.pauses(w)

@@ -10,7 +10,9 @@ served forward (``Served``), which tags each call with the items it
 carried, times the host side of the call and never blocks.
 
 Everything that belongs to one configuration, mix or metric is a file
-that this module finds by the name ``BENCHMARK.json`` gives it.
+that this module finds by the name ``BENCHMARK.json`` gives it, and
+everything it knows of a model is in ``models/<model>.py``, found by the
+configuration file's ``model`` key (see ``load_model``).
 """
 from __future__ import annotations
 
@@ -55,13 +57,14 @@ class Cell:
     e2e: list[dict]           # metric entries of an untraced run
     per_layer: list[dict]     # metric entries of a traced run
     root: str                 # directory of the benchmark's files
+    model: object             # the configuration's ``models/<model>.py``
 
 
 def find_cell(bench: dict, name: str, repo: str) -> Cell:
     """The cell ``name`` of ``bench``; files are found under ``repo``
     (configurations by their ``file``) and under the benchmark's first
     path (mixes as ``traffic/<mix>.json``, metrics as
-    ``metrics/<metric>.py``)."""
+    ``metrics/<metric>.py``, models as ``models/<model>.py``)."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
@@ -71,7 +74,8 @@ def find_cell(bench: dict, name: str, repo: str) -> Cell:
     cfg = load_json(os.path.join(repo, entry["file"]))
     mix = load_json(os.path.join(root, "traffic", w["traffic"] + ".json"))
     return Cell(name, cfg, mix, w["chips"], cell_metrics(bench, name, False),
-                cell_metrics(bench, name, True), root)
+                cell_metrics(bench, name, True), root,
+                load_model(root, cfg["model"]))
 
 
 def cell_metrics(bench: dict, cell: str, traced: bool) -> list[dict]:
@@ -88,14 +92,37 @@ def cell_metrics(bench: dict, cell: str, traced: bool) -> list[dict]:
                 else m["moves"] in moved)]
 
 
-def load_reader(root: str, name: str):
-    """``metrics/<name>.py``'s ``read(run) -> float | None``."""
-    path = os.path.join(root, "metrics", name + ".py")
+def _load(root: str, kind: str, name: str):
+    path = os.path.join(root, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(root: str, name: str):
+    """``metrics/<name>.py``'s ``read(run) -> float | None``."""
+    return _load(root, "metrics", name).read
+
+
+def load_model(root: str, name: str):
+    """``models/<name>.py``: all that the harness, the check, the control
+    and the metric readers know of a model family.
+
+    * ``rec_config(cfg)``: the program's ``RecConfig`` for a
+      configuration file, every field read from the file;
+    * ``draw_pool(cfg, rows, key) -> {name: array}``: the payload pool,
+      one entry per input of the served forward, rows on the leading
+      axis, drawn from the PRNG ``key``;
+    * ``init_weights(seed, cfg)`` and ``forward(w, batch, *, store,
+      precision) -> (B,)``: the plain float32 reference on a batch of the
+      pool's entries (``reference.py`` says what ``store`` and
+      ``precision`` mean), which imports nothing of the program;
+    * ``flops_per_item(cfg)`` and ``call_bytes(cfg, bucket, inputs)``: what
+      one forward call needs (``costs.py``), read only by the readers of
+      roofline and peak shares."""
+    return _load(root, "models", name)
 
 
 def use_compile_cache(repo: str) -> None:
@@ -118,24 +145,11 @@ def use_numerics(cfg: dict) -> None:
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
 
 
-def rec_config(cfg: dict):
-    """The program's ``RecConfig`` for a configuration file."""
-    from repro.models.recsys import RecConfig
-    if cfg["model"] != "dlrm":
-        raise ValueError(f"no served model for {cfg['model']!r}")
-    return RecConfig(name=cfg["name"], interaction="dot",
-                     n_dense=cfg["n_dense"], dense_fc=tuple(cfg["dense_fc"]),
-                     predict_fc=tuple(cfg["predict_fc"]),
-                     n_tables=cfg["n_tables"], vocab=cfg["vocab"],
-                     embed_dim=cfg["embed_dim"], hotness=cfg["hotness"],
-                     pooling=cfg["pooling"], dtype=cfg["dtype"])
-
-
-def recsys_model(cfg: dict, seed: int):
+def recsys_model(model, cfg: dict, seed: int):
     """The program's served ``apply_fn`` with its weights made on the
     device from ``seed``."""
     from repro.serve.models import recsys_model as build
-    apply_fn, _, params = build(rec_config(cfg), seed=seed, max_rows=1)
+    apply_fn, _, params = build(model.rec_config(cfg), seed=seed, max_rows=1)
     return apply_fn, params
 
 
@@ -168,7 +182,8 @@ class Payload:
     The one feeder of a node releases queries in arrival order, so the
     k-th call is query k.  Its rows start at the schedule's offset, and
     each row carries the tag ``k * ITEM_STRIDE + item`` under
-    ``ITEM_KEY``, which the runtime slices and pads with the rest."""
+    ``ITEM_KEY``, which the runtime slices and pads with the rest of the
+    pool's entries."""
 
     def __init__(self, pool: dict, sched: traffic.Schedule):
         self.pool = pool
@@ -186,8 +201,7 @@ class Payload:
             o = int(self.sched.offsets[q])
             tags = np.arange(q * traffic.ITEM_STRIDE,
                              q * traffic.ITEM_STRIDE + size, dtype=np.int32)
-            return {"dense": self.pool["dense"][o:o + size],
-                    "sparse": self.pool["sparse"][o:o + size],
+            return {**{k: v[o:o + size] for k, v in self.pool.items()},
                     ITEM_KEY: tags}
 
 
@@ -280,6 +294,7 @@ class Tracer:
 
 @dataclasses.dataclass
 class Setup:
+    model: object
     cfg: dict
     seed: int
     apply_fn: object
@@ -303,17 +318,16 @@ def buckets_of(sizes: np.ndarray, serving: dict) -> list[int]:
                    for n in np.unique(request_rows(sizes, bsz))})
 
 
-def set_up(cfg: dict, mix: dict, seed: int, buckets: list[int],
-           build=None) -> Setup:
+def set_up(cell: Cell, seed: int, buckets: list[int], build=None) -> Setup:
     """Weights on the device, the payload pool, the buckets warmed.
 
-    ``build(cfg, seed) -> (apply_fn, params)`` makes what is served: the
-    program's model, or another put in its place."""
+    ``build(model, cfg, seed) -> (apply_fn, params)`` makes what is
+    served: the program's model, or another put in its place."""
     import jax
     from repro.cluster import BucketedDeviceModel
-    apply_fn, params = (build or recsys_model)(cfg, seed)
-    pool = traffic.make_pool(mix, cfg["n_dense"], cfg["n_tables"],
-                             cfg["hotness"], cfg["vocab"], seed)
+    model, cfg = cell.model, cell.cfg
+    apply_fn, params = (build or recsys_model)(model, cfg, seed)
+    pool = model.draw_pool(cfg, cell.mix["pool_rows"], traffic.pool_key(seed))
     jax.block_until_ready(params)
     secs = []
     for b in buckets:
@@ -326,7 +340,7 @@ def set_up(cfg: dict, mix: dict, seed: int, buckets: list[int],
     # robin never reads it
     curve = BucketedDeviceModel(np.asarray(buckets),
                                 np.maximum.accumulate(np.asarray(secs)))
-    return Setup(cfg, seed, apply_fn, params, pool, curve)
+    return Setup(model, cfg, seed, apply_fn, params, pool, curve)
 
 
 @dataclasses.dataclass
@@ -428,8 +442,8 @@ def verify(s: Setup, w: Window) -> dict:
         c.out = None
     s.apply_fn = s.params = None
     gc.collect()
-    weights = reference.init_weights(s.seed, s.cfg)
-    ref = reference.logits(weights, s.pool["dense"], s.pool["sparse"],
+    weights = s.model.init_weights(s.seed, s.cfg)
+    ref = reference.logits(s.model.forward, weights, s.pool,
                            store=s.cfg["dtype"])
     del weights
     return check.compare(check.served_items(tags, outs), ref, w.sched,
@@ -448,6 +462,7 @@ class Run:
     setup_s: float
     window: Window
     pool: dict
+    model: object = None      # the configuration's ``models/<model>.py``
 
 
 def read_metrics(root: str, entries: list[dict], run: Run) -> dict:
@@ -466,8 +481,7 @@ def serve_cell(cell: Cell, *, seed: int, seconds: float, traced: bool,
     """Set up and serve one window of ``cell``."""
     rate = cell.mix["load_of_knee"] * cell.cfg["knee_qps"]
     sched = traffic.schedule(cell.mix, rate, seconds, seed)
-    s = set_up(cell.cfg, cell.mix, seed,
-               buckets_of(sched.sizes, cell.cfg["serving"]))
+    s = set_up(cell, seed, buckets_of(sched.sizes, cell.cfg["serving"]))
     trace_dir = tempfile.mkdtemp(prefix="chipbench-") if traced else None
     try:
         setup_s = time.monotonic() - t_start
@@ -476,7 +490,7 @@ def serve_cell(cell: Cell, *, seed: int, seconds: float, traced: bool,
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    return Run(cell.cfg, cell.mix, peak, setup_s, w, s.pool), s
+    return Run(cell.cfg, cell.mix, peak, setup_s, w, s.pool, cell.model), s
 
 
 def run_cell(cell: Cell, *, seed: int, seconds: float, traced: bool,

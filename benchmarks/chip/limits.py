@@ -34,16 +34,14 @@ def control_numerics(cfg: dict) -> dict:
     return {"store": "float8_e4m3fn", "precision": "default"}
 
 
-def control_model(cfg: dict, seed: int):
-    """The reference in the program's place, one step below the
+def control_model(model, cfg: dict, seed: int):
+    """The model's reference in the program's place, one step below the
     configuration's numerics."""
     import jax
-
-    import reference
-    w = reference.init_weights(seed, cfg)
-    fwd = jax.jit(lambda w, d, s: reference.forward(
-        w, d, s, **control_numerics(cfg)))
-    return (lambda batch: fwd(w, batch["dense"], batch["sparse"])), w
+    w = model.init_weights(seed, cfg)
+    fwd = jax.jit(lambda w, batch: model.forward(w, batch,
+                                                 **control_numerics(cfg)))
+    return (lambda batch: fwd(w, batch)), w
 
 
 def reading(cell, seed: int, seconds: float, build=None) -> dict:
@@ -51,7 +49,7 @@ def reading(cell, seed: int, seconds: float, build=None) -> dict:
     import traffic
     rate = cell.mix["load_of_knee"] * cell.cfg["knee_qps"]
     sched = traffic.schedule(cell.mix, rate, seconds, seed)
-    s = harness.set_up(cell.cfg, cell.mix, seed,
+    s = harness.set_up(cell, seed,
                        harness.buckets_of(sched.sizes, cell.cfg["serving"]),
                        build=build)
     w = harness.serve_window(s, sched, seconds, cell.mix["at_close"])

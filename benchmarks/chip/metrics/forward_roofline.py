@@ -1,8 +1,9 @@
 """The served forward's share of its roofline, in percent: over the traced
 calls, the least time the chip could take for each (the larger of its
 operations over peak FLOP/s and its bytes over peak bytes/s, from
-``costs.py``, at the bucket it ran and with the distinct embedding rows its
-ids name), summed, over the device time of those calls' executions."""
+``costs.py`` and the cell's model module, at the bucket it ran and with
+the pool rows it carried), summed, over the device time of those calls'
+executions."""
 import costs
 import traffic
 
@@ -20,6 +21,7 @@ def read(run):
     for span, _ in pairs:
         c = calls[int(span.stats["call"])]
         rows, ok = traffic.pool_rows(run.window.sched, c.tags)
-        gathered = costs.distinct_rows(run.pool["sparse"][rows[ok]])
-        least += costs.least_seconds(run.cfg, c.bucket, gathered, run.peak)
+        inputs = {k: v[rows[ok]] for k, v in run.pool.items()}
+        least += costs.least_seconds(run.model, run.cfg, c.bucket, inputs,
+                                     run.peak)
     return 100.0 * least / device

@@ -1,7 +1,7 @@
 """The whole step's share of the chip's peak, in percent: the items the
-traced calls served times the model's operations per item, over the
-traced stretch's seconds times the chip's peak FLOP/s."""
-import costs
+traced calls served times the model's operations per item (the cell's
+model module), over the traced stretch's seconds times the chip's peak
+FLOP/s."""
 
 
 def read(run):
@@ -11,5 +11,5 @@ def read(run):
     items = sum(int(s.stats["rows"]) for s, _ in p.paired_forward())
     if not items:
         return None
-    return 100.0 * items * costs.flops_per_item(run.cfg) / (
+    return 100.0 * items * run.model.flops_per_item(run.cfg) / (
         p.window_s * run.peak["flops_per_s"])

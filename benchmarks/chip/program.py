@@ -294,19 +294,19 @@ def traced_buckets(profile) -> list[int]:
 
 def forward_lowered(run, buckets) -> dict:
     """The served forward lowered at each bucket for the default device, as
-    the program serves it."""
+    the program serves it: on the params it serves
+    (``serve.models.served_param_shapes``, tables packed) and the run's
+    payload shapes."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
-    import harness
-    from repro.models import recsys
-    from repro.serve.models import served_forward
-    rc = harness.rec_config(run.cfg)
+    from repro.serve.models import served_forward, served_param_shapes
+    rc = run.model.rec_config(run.cfg)
     dev = jax.devices()[0]
     on = SingleDeviceSharding(dev)
     params = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on),
-        jax.eval_shape(lambda k: recsys.init(k, rc), jax.random.PRNGKey(0)))
+        served_param_shapes(rc))
     fwd = served_forward(dev.platform)
     out = {}
     for b in buckets:

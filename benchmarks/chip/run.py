@@ -53,8 +53,8 @@ def sweep(cell, rates: list[float], seconds: float, seed: int) -> None:
     import traffic
     scheds = [traffic.schedule(cell.mix, r, seconds, seed) for r in rates]
     sizes = np.concatenate([s.sizes for s in scheds])
-    s = harness.set_up(cell.cfg, cell.mix, seed,
-                       harness.buckets_of(sizes, cell.cfg["serving"]))
+    s = harness.set_up(cell, seed, harness.buckets_of(sizes,
+                                                      cell.cfg["serving"]))
     sla = cell.cfg["sla_ms"]
     for rate, sched in zip(rates, scheds):
         w = harness.serve_window(s, sched, seconds, "wait", drain_s=5.0)

@@ -16,6 +16,7 @@ import traffic  # noqa: E402
 from repro.cluster.backend import CompletedQuery  # noqa: E402
 
 SMOKE = os.path.join(BENCH, "tests", "fixtures", "dlrm-smoke.json")
+DLRM = harness.load_model(BENCH, "dlrm")
 
 
 def window(times, sizes, done, at_close="wait", errors=(), calls=(),
@@ -120,7 +121,7 @@ def test_costs_flops_within_a_factor_of_xla(bucket):
     import jax
     from repro.models import recsys
     cfg = harness.load_json(SMOKE)
-    rc = harness.rec_config(cfg)
+    rc = DLRM.rec_config(cfg)
     params = jax.eval_shape(lambda k: recsys.init(k, rc),
                             jax.random.PRNGKey(0))
     batch = {"dense": jax.ShapeDtypeStruct((bucket, rc.n_dense), np.float32),
@@ -129,22 +130,26 @@ def test_costs_flops_within_a_factor_of_xla(bucket):
     ca = jax.jit(recsys.forward, static_argnums=1).lower(
         params, rc, batch).compile().cost_analysis()
     ca = ca[0] if isinstance(ca, list) else ca
-    ratio = ca["flops"] / costs.call_flops(cfg, bucket)
+    ratio = ca["flops"] / (bucket * DLRM.flops_per_item(cfg))
     assert 1 / 1.5 <= ratio <= 1.5
 
 
 def test_costs_match_the_published_widths():
     rmc1 = harness.load_json(os.path.join(BENCH, "configs", "dlrm-rmc1.json"))
-    every = 64 * 10 * 80               # no id named twice
-    assert costs.call_bytes(rmc1, 64, every) == pytest.approx(7.39e6,
-                                                               rel=0.01)
-    assert costs.weight_bytes(rmc1) == 567428    # 141,857 float32 weights
+    # no id named twice: 64 rows x 80 distinct ids in each of 10 tables
+    every = {"sparse": np.arange(64 * 10 * 80).reshape(64, 10, 80)}
+    assert DLRM.distinct_rows(every["sparse"]) == 64 * 10 * 80
+    assert DLRM.call_bytes(rmc1, 64, every) == pytest.approx(7.39e6,
+                                                              rel=0.01)
+    assert DLRM.weight_bytes(rmc1) == 567428    # 141,857 float32 weights
     peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     # both are bound by bytes at bucket 64
-    assert costs.least_seconds(rmc1, 64, every, peak) == pytest.approx(
-        costs.call_bytes(rmc1, 64, every) / 819e9)
+    assert costs.least_seconds(DLRM, rmc1, 64, every, peak) == \
+        pytest.approx(DLRM.call_bytes(rmc1, 64, every) / 819e9)
     # ids named twice are read once: the heavy head of the id law
-    assert costs.call_bytes(rmc1, 64, every // 2) < costs.call_bytes(
+    half = {"sparse": every["sparse"] // 2}
+    assert DLRM.distinct_rows(half["sparse"]) == 64 * 10 * 80 // 2
+    assert DLRM.call_bytes(rmc1, 64, half) < DLRM.call_bytes(
         rmc1, 64, every)
 
 
@@ -152,7 +157,7 @@ def test_distinct_rows_counts_each_table_apart():
     ids = np.array([[[1, 1, 2], [1, 3, 3]],
                     [[2, 2, 2], [1, 1, 1]]])      # (rows, tables, hotness)
     # table 0 names 1 and 2, table 1 names 1 and 3
-    assert costs.distinct_rows(ids) == 4
+    assert DLRM.distinct_rows(ids) == 4
 
 
 @pytest.mark.parametrize("seed", [0, 2**31 + 7])
@@ -160,9 +165,9 @@ def test_reference_matches_the_served_model(seed):
     import jax
     from repro.models import recsys
     cfg = harness.load_json(SMOKE)
-    rc = harness.rec_config(cfg)
+    rc = DLRM.rec_config(cfg)
     params = recsys.init(jax.random.PRNGKey(seed), rc)
-    w = reference.init_weights(seed, cfg)
+    w = DLRM.init_weights(seed, cfg)
     # the same values from the seed, to the rounding of the last bit
     # (XLA may fold the init's scale into the normal draw differently)
     same = dict(rtol=1e-6, atol=1e-7)
@@ -172,15 +177,71 @@ def test_reference_matches_the_served_model(seed):
                           params["dense_mlp"] + params["predict"][0]):
         np.testing.assert_allclose(wt, p["w"], **same)
         np.testing.assert_array_equal(b, p["b"])
-    mix = {"pool_rows": 512}
-    pool = traffic.make_pool(mix, cfg["n_dense"], cfg["n_tables"],
-                             cfg["hotness"], cfg["vocab"], seed)
+    pool = DLRM.draw_pool(cfg, 512, traffic.pool_key(seed))
     got = np.asarray(recsys.forward(params, rc, pool))
-    want = reference.logits(w, pool["dense"], pool["sparse"], block=128)
+    want = reference.logits(DLRM.forward, w, pool, block=128)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    low = reference.logits(w, pool["dense"], pool["sparse"], block=128,
+    low = reference.logits(DLRM.forward, w, pool, block=128,
                            store="bfloat16", precision="default")
     assert np.max(np.abs(low - want)) > 100 * np.max(np.abs(got - want))
+
+
+# recorded from the tree before DLRM's code moved into models/dlrm.py: the
+# smoke configuration, 512 pool rows, seed 2**31 + 1234; sha256 of the
+# arrays' bytes
+PINNED_DLRM = {
+    "dense":
+        "f53880db0fb4f3447a272e7ff28528b9c9cda5223360924408ba786a54c29fd4",
+    "sparse":
+        "39c64536d71f1c0461693c83a8dbc3f881ac5dd99c31f00e9ed880bdd0298c45",
+    "weights":
+        "2a364fe85af5026af52a4d0bf35ef021a15d5de9368618364c53c74e91ac9e6e",
+    "logits":
+        "a88fa6038a6eba1e6eaa89a471ecf10da8a05d96146adecc19fc556b158b0536",
+    "first": ["0x1.35b5260000000p-2", "0x1.c1932a0000000p-1",
+              "0x1.d66eb80000000p-1", "0x1.8319520000000p-2"],
+    "low":
+        "86c9d521c1ffbcfad42854214824977d09ec99d266f32d2e799aa31206533eaa",
+}
+
+
+def test_the_dlrm_module_draws_and_computes_what_the_benchmark_did():
+    """The pool, the reference weights and logits (at the reference's and
+    at the control's numerics) and the costs, bit for bit as before the
+    model's code moved behind ``models/<model>.py``."""
+    import hashlib
+
+    import jax
+
+    def sha(a):
+        return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()
+    cfg = harness.load_json(SMOKE)
+    seed = 2**31 + 1234
+    pool = DLRM.draw_pool(cfg, 512, traffic.pool_key(seed))
+    assert sorted(pool) == ["dense", "sparse"]
+    assert pool["dense"].shape == (512, 16)
+    assert pool["dense"].dtype == np.float32
+    assert pool["sparse"].shape == (512, 4, 4)
+    assert pool["sparse"].dtype == np.int32
+    assert sha(pool["dense"]) == PINNED_DLRM["dense"]
+    assert sha(pool["sparse"]) == PINNED_DLRM["sparse"]
+    w = DLRM.init_weights(seed, cfg)
+    assert sha(np.concatenate([np.ravel(np.asarray(x)) for x in
+                               jax.tree_util.tree_leaves(w)])) == \
+        PINNED_DLRM["weights"]
+    got = reference.logits(DLRM.forward, w, pool, block=128)
+    assert sha(got) == PINNED_DLRM["logits"]
+    assert [float(x).hex() for x in got[:4]] == PINNED_DLRM["first"]
+    low = reference.logits(DLRM.forward, w, pool, block=128,
+                           store="bfloat16", precision="default")
+    assert sha(low) == PINNED_DLRM["low"]
+    assert DLRM.flops_per_item(cfg) == 2969
+    rows = {k: v[:50] for k, v in pool.items()}
+    assert DLRM.call_bytes(cfg, 64, rows) == 28708
+    rows = {k: v[100:103] for k, v in pool.items()}
+    assert DLRM.call_bytes(cfg, 4, rows) == 7060
+    rmc1 = harness.load_json(os.path.join(BENCH, "configs", "dlrm-rmc1.json"))
+    assert DLRM.flops_per_item(rmc1) == 316001
 
 
 def test_trace_union_labels_and_pairs():

@@ -1,7 +1,8 @@
 """The harness end to end on the CPU at a smoke configuration, through
 ``drive_fleet`` and the live node: ``harness.run_cell``, whose platform
-check is ``run.py``'s alone; the control and planted faults; and a
-configuration, mix, cell and metric added as files alone."""
+check is ``run.py``'s alone; the control and planted faults; a
+configuration, mix, cell and metric added as files alone; and a second
+model family added as files alone."""
 import copy
 import importlib.util
 import json
@@ -21,6 +22,7 @@ import harness  # noqa: E402
 import limits  # noqa: E402
 
 SMOKE = "benchmarks/chip/tests/fixtures/dlrm-smoke.json"
+FIXTURES = os.path.join(BENCH, "tests", "fixtures")
 SECONDS = 1.5
 SEED = 2**31 + 99
 
@@ -75,20 +77,17 @@ def test_the_control_in_the_programs_place_is_not_correct(monkeypatch):
     assert r["checks"]["rms_gap"]["value"] > r["checks"]["rms_gap"]["limit"]
 
 
-@pytest.mark.parametrize("fault, caught", [
-    ("altered", "max_gap"), ("reversed", "max_gap"), ("half", "max_gap"),
-    ("lost", "failed_queries")])
-def test_a_faulty_served_path_is_not_correct(monkeypatch, fault, caught):
-    """An answer altered where it is produced; a call's answers handed
-    back in the wrong order; half of a call's rows left out, their
-    answers copied from the rest; a request whose answer never comes."""
+def faulty(fault: str):
+    """The program's model, built as ``harness.recsys_model`` builds it,
+    with ``fault`` planted in its 30th call of more than one row (past the
+    warm-up's)."""
     real = harness.recsys_model
 
-    def broken(cfg, seed):
-        apply_fn, params = real(cfg, seed)
+    def broken(model, cfg, seed):
+        apply_fn, params = real(model, cfg, seed)
         calls = [0]
 
-        def faulty(batch):       # the 30th call, past the warm-up's
+        def served(batch):       # the 30th call, past the warm-up's
             out = np.asarray(apply_fn(batch))
             if len(out) > 1:
                 calls[0] += 1
@@ -106,8 +105,18 @@ def test_a_faulty_served_path_is_not_correct(monkeypatch, fault, caught):
                 else:
                     raise RuntimeError("request lost")
             return out
-        return faulty, params
-    monkeypatch.setattr(harness, "recsys_model", broken)
+        return served, params
+    return broken
+
+
+@pytest.mark.parametrize("fault, caught", [
+    ("altered", "max_gap"), ("reversed", "max_gap"), ("half", "max_gap"),
+    ("lost", "failed_queries")])
+def test_a_faulty_served_path_is_not_correct(monkeypatch, fault, caught):
+    """An answer altered where it is produced; a call's answers handed
+    back in the wrong order; half of a call's rows left out, their
+    answers copied from the rest; a request whose answer never comes."""
+    monkeypatch.setattr(harness, "recsys_model", faulty(fault))
     r = run("smoke-prod-steady")
     assert not r["correct"]
     c = r["checks"][caught]
@@ -159,6 +168,70 @@ def test_new_files_alone_add_a_configuration_mix_cell_and_metric(tmp_path):
     got = harness.read_metrics(cell.root, cell.per_layer, run_)
     assert got["calls_per_query"]["value"] >= 1.0
     assert {p: p.read_bytes() for p in before} == before
+
+
+def test_new_files_alone_add_a_model_family(tmp_path, monkeypatch):
+    """A copy of the benchmark gains a model module (``models/toy_seq.py``,
+    the program's DIN smoke configuration, whose inputs are ``sparse``,
+    ``history``, ``hist_mask`` and ``target``, no ``dense``), its
+    configuration, a mix and a cell as new files and entries: the
+    harness serves every pool entry to the program and checks every
+    answer with no edit to a file that was there, and an answer altered
+    where it is produced is not correct."""
+    root = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(BENCH, root,
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    shutil.copy(os.path.join(FIXTURES, "toy_seq.py"),
+                root / "models" / "toy_seq.py")
+    shutil.copy(os.path.join(FIXTURES, "din-smoke.json"),
+                root / "configs" / "din-smoke.json")
+    mix = harness.load_json(os.path.join(BENCH, "traffic",
+                                         "prod-steady.json"))
+    mix.update(load_of_knee=1.0, pool_rows=2048)
+    (root / "traffic" / "toy-steady.json").write_text(json.dumps(mix))
+    bench = copy.deepcopy(harness.load_json(os.path.join(REPO,
+                                                         "BENCHMARK.json")))
+    bench["configs"].append({"name": "din-smoke",
+                             "file": "benchmarks/chip/configs/din-smoke.json"})
+    bench["workloads"].append({"name": "din-steady", "config": "din-smoke",
+                               "traffic": "toy-steady", "chips": 1})
+    for m in bench["end_to_end"]:
+        if m["name"] == "p50_ms":
+            m["workloads"].append("din-steady")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = harness.find_cell(bench, "din-steady", str(tmp_path))
+    assert cell.model.rec_config(cell.cfg).interaction == "din"
+
+    seen = []
+    real = harness.recsys_model
+
+    def spy(model, cfg, seed):
+        apply_fn, params = real(model, cfg, seed)
+
+        def served(batch):
+            seen.append(sorted(batch))
+            return apply_fn(batch)
+        return served, params
+    monkeypatch.setattr(harness, "recsys_model", spy)
+    r = harness.run_cell(cell, seed=SEED, seconds=SECONDS, traced=False,
+                         t_start=time.monotonic(), peak={})
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == {"p50_ms", "setup_s"}
+    assert r["attempted"] == round(30.0 * SECONDS) and r["failed"] == 0
+    assert r["checks"]["max_gap"]["value"] < 1e-5
+    assert r["checks"]["unserved_items"]["value"] == 0
+    assert r["notes"]["compiles_in_window"] == 0
+    assert seen and all(keys == ["hist_mask", "history", "sparse", "target"]
+                        for keys in seen)
+    assert {p: p.read_bytes() for p in before} == before
+
+    monkeypatch.setattr(harness, "recsys_model", faulty("altered"))
+    r = harness.run_cell(cell, seed=SEED, seconds=SECONDS, traced=False,
+                         t_start=time.monotonic(), peak={})
+    assert not r["correct"]
+    c = r["checks"]["max_gap"]
+    assert c["value"] > c["limit"]
 
 
 def test_the_entry_point_refuses_a_platform_without_a_tpu(capsys):

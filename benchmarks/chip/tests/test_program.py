@@ -25,6 +25,7 @@ import traffic  # noqa: E402
 FIXTURES = os.path.join(BENCH, "tests", "fixtures")
 SMOKE = os.path.join(FIXTURES, "dlrm-smoke.json")
 RMC1 = os.path.join(BENCH, "configs", "dlrm-rmc1.json")
+DLRM = harness.load_model(BENCH, "dlrm")
 
 
 def read(name, run):
@@ -122,25 +123,54 @@ def test_clock_fit_and_coverage_arithmetic():
 # ------------------------------------------------- the forward's scopes
 
 
+def smoke_run():
+    """A run of the smoke configuration with a 64-row pool, for lowering."""
+    cfg = harness.load_json(SMOKE)
+    pool = DLRM.draw_pool(cfg, 64, traffic.pool_key(3))
+    return harness.Run(cfg, {}, {}, 0.0, None, pool, DLRM)
+
+
 def test_scope_tables_name_the_four_scopes_of_the_served_forward():
     import jax
-    from repro.models import recsys
-    from repro.serve.models import served_forward
-    cfg = harness.load_json(SMOKE)
-    pool = traffic.make_pool({"pool_rows": 64}, cfg["n_dense"],
-                             cfg["n_tables"], cfg["hotness"], cfg["vocab"], 3)
-    run = harness.Run(cfg, {}, {}, 0.0, None, pool)
+    from repro.serve.models import init_params, served_forward
+    run = smoke_run()
+    cfg, pool = run.cfg, run.pool
     tables = program.forward_scope_tables(run, [4, 64])
     for t in tables.values():
         assert set(t.values()) == set(program.SCOPES)
     # the table of the program as served: compiled from the arrays a
-    # call passes, its instructions are the same
-    rc = harness.rec_config(cfg)
-    params = recsys.init(jax.random.PRNGKey(3), rc)
+    # call passes, tables packed, its instructions are the same
+    rc = DLRM.rec_config(cfg)
+    params = init_params(jax.random.PRNGKey(3), rc)
     batch = {k: np.asarray(v[:4]) for k, v in pool.items()}
     served = served_forward(jax.devices()[0].platform).lower(
         params, rc, batch).compile().as_text()
     assert program.scope_table(served) == tables[4]
+
+
+def test_the_scope_table_maps_the_packed_tables_gather_to_its_scope():
+    """The smoke configuration's tables (4 x 1000 rows of 8 lanes) are
+    served packed 16 rows to a 128-lane row: the lowering the scope tables
+    come from takes them so, and every op of its entry computation that
+    reads them, and the gather inside, lies in ``embedding_gather``."""
+    import re
+    hlo = program.forward_lowered(smoke_run(), [64])[64].compile().as_text()
+    table = program.scope_table(hlo)
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")].splitlines()[1:]
+    param = next(line for line in entry
+                 if "parameter(" in line and "f32[4,63,128]" in line)
+    name = re.escape(param.split("=")[0].strip())
+    users = [line for line in entry if line is not param
+             and re.search(r"\(.*" + name + r"[,)]", line)]
+    assert users
+    for line in users:
+        assert table[program._op_key(line)] == "embedding_gather", line
+    gathers = [line for line in hlo.splitlines()
+               if " gather(" in line and "slice_sizes={1,128}" in line]
+    assert gathers
+    for line in gathers:
+        assert table[program._op_key(line)] == "embedding_gather", line
 
 
 # Run in a process of its own: the persistent cache is set up once per
@@ -152,13 +182,13 @@ import contextlib, sys
 import jax
 sys.path[:0] = [{bench!r}, {src!r}]
 import harness, program, traffic
+DLRM = harness.load_model({bench!r}, "dlrm")
 jax.config.update("jax_compilation_cache_dir", {cache!r})
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 cfg = harness.load_json({smoke!r})
-pool = traffic.make_pool({{"pool_rows": 64}}, cfg["n_dense"],
-                         cfg["n_tables"], cfg["hotness"], cfg["vocab"], 3)
-run = harness.Run(cfg, {{}}, {{}}, 0.0, None, pool)
+pool = DLRM.draw_pool(cfg, 64, traffic.pool_key(3))
+run = harness.Run(cfg, {{}}, {{}}, 0.0, None, pool, DLRM)
 lower = lambda: program.forward_lowered(run, [4])[4]
 scoped = jax.named_scope
 jax.named_scope = lambda name: contextlib.nullcontext()
@@ -233,7 +263,7 @@ def test_existing_device_metrics_reduce_as_before_on_the_steady_trace(
     cfg = harness.load_json(RMC1)
     peak = harness.load_json(os.path.join(BENCH, "peaks.json"))[
         "TPU v5 lite"]
-    run = harness.Run(cfg, {}, peak, 0.0, _window(steady), {})
+    run = harness.Run(cfg, {}, peak, 0.0, _window(steady), {}, DLRM)
     assert read("device_idle_share", run) == pytest.approx(
         40.523449189393, rel=1e-9)
     assert read("forward_device_ms.mean", run) == pytest.approx(
@@ -288,7 +318,7 @@ def overload():
     cfg = harness.load_json(RMC1)
     peak = harness.load_json(os.path.join(BENCH, "peaks.json"))[
         "TPU v5 lite"]
-    return harness.Run(cfg, {}, peak, 0.0, w, {}), rec
+    return harness.Run(cfg, {}, peak, 0.0, w, {}, DLRM), rec
 
 
 @pytest.fixture

@@ -1,5 +1,6 @@
 """The one traffic generator: reads a mix file's parameters and turns them,
-with a run's seed, into an open-loop schedule and the payload pool.
+with a run's seed, into an open-loop schedule; and the draws that every
+model's payload pool (``models/<model>.py``'s ``draw_pool``) shares.
 
 A mix file (``traffic/<mix>.json``) holds only data:
 
@@ -18,8 +19,9 @@ tail percentile at 0.8 of the knee moves by a fifth with the order of the
 bursts alone (runs of 20 s, PERF.md), so the order is part of the work,
 and runs of different seeds then differ by the system's spread, not the
 draw's.  The seed draws the data: which pool rows each query reads, the
-pool and the weights.  Arrivals are a Poisson process (exponential gaps)
-scaled so that the schedule fills ``[0, seconds)`` exactly.
+pool (from ``pool_key``) and the weights.  Arrivals are a Poisson process
+(exponential gaps) scaled so that the schedule fills ``[0, seconds)``
+exactly.
 """
 from __future__ import annotations
 
@@ -97,27 +99,13 @@ def pool_key(seed: int):
     return jax.random.fold_in(key, 0x706F6F6C)
 
 
-def make_pool(mix: dict, n_dense: int, n_tables: int, hotness: int,
-              vocab: int, seed: int) -> dict[str, np.ndarray]:
-    """The payload pool as host numpy arrays, drawn on the default device.
-
-    ``dense`` (rows, n_dense) float32 standard normal; ``sparse`` (rows,
-    n_tables, hotness) int32 ids over every row of every table, with the
+def log_uniform_ids(key, shape: tuple[int, ...], vocab: int):
+    """int32 ids over every row of a ``vocab``-row table with the
     log-uniform heavy head of ``repro.data.synthetic._zipf_ids``:
-    ``floor(vocab ** u) - 1`` for ``u`` uniform on [0, 1).
-    """
+    ``floor(vocab ** u) - 1`` for ``u`` uniform on [0, 1), drawn from
+    ``key`` (traceable)."""
     import jax
     import jax.numpy as jnp
-
-    rows = mix["pool_rows"]
-
-    @jax.jit
-    def draw(key):
-        kd, ks = jax.random.split(key)
-        dense = jax.random.normal(kd, (rows, n_dense), jnp.float32)
-        u = jax.random.uniform(ks, (rows, n_tables, hotness), jnp.float32)
-        ids = jnp.floor(jnp.exp(u * np.log(vocab))).astype(jnp.int32) - 1
-        return dense, jnp.clip(ids, 0, vocab - 1)
-
-    dense, sparse = jax.device_get(draw(pool_key(seed)))
-    return {"dense": dense, "sparse": sparse}
+    u = jax.random.uniform(key, shape, jnp.float32)
+    ids = jnp.floor(jnp.exp(u * np.log(vocab))).astype(jnp.int32) - 1
+    return jnp.clip(ids, 0, vocab - 1)
