@@ -32,10 +32,13 @@ def component_times(arch: str) -> dict[str, float]:
     if cfg.n_tables:
         emb = jax.jit(lambda p, b: recsys._sparse_pooled(p, cfg, b["sparse"]))
         out["embedding"] = timeit(lambda: emb(params, batch), iters=5)
-    if cfg.has_history:
+    if cfg.item_fields:
+        tab = jax.jit(lambda p, b: recsys._behaviors(p, cfg, b))
+    elif cfg.has_history:
         tab = jax.jit(lambda p, b: (
             jax.numpy.take(p["item_table"], b["history"], axis=0),
             jax.numpy.take(p["item_table"], b["target"], axis=0)))
+    if cfg.has_history:
         out["embedding"] = out.get("embedding", 0.0) + timeit(
             lambda: tab(params, batch), iters=5)
     return out

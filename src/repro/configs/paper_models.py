@@ -77,9 +77,12 @@ PAPER_MODELS: dict[str, RecConfig] = {
     "din": RecConfig(
         name="din", interaction="din", n_tables=8, vocab=_V, embed_dim=64,
         hotness=1, seq_len=256, item_vocab=_V, predict_fc=(200, 80, 1)),
+    # widths Table I leaves out from the authors' code (mouna99/dien,
+    # script/train.py): 18 per id, a behavior is item ⊕ category (36),
+    # GRU hidden 36, history of 100
     "dien": RecConfig(
-        name="dien", interaction="dien", n_tables=8, vocab=_V, embed_dim=64,
-        hotness=1, seq_len=32, item_vocab=_V, gru_hidden=64,
+        name="dien", interaction="dien", n_tables=8, vocab=_V, embed_dim=18,
+        hotness=1, seq_len=100, item_vocab=_V, item_fields=2, gru_hidden=36,
         predict_fc=(200, 80, 1)),
 }
 

@@ -46,8 +46,10 @@ def recsys_flops_per_sample(cfg: RecConfig) -> int:
     elif it == "din":
         f += _mlp_flops(4 * d, (80, 40, 1)) * cfg.seq_len
     elif it == "dien":
-        g = cfg.gru_hidden
-        f += cfg.seq_len * (6 * d * g + 6 * g * g) * 2      # GRU + AUGRU
+        g, w, t = cfg.gru_hidden, cfg.item_width, cfg.seq_len
+        f += t * (6 * w * g + 6 * g * g)                     # GRU
+        f += 2 * g * w + 2 * t * g                           # attention
+        f += t * 12 * g * g                                  # AUGRU
     elif it == "mind":
         f += cfg.capsule_iters * 2 * cfg.seq_len * cfg.n_interests * d
         f += 2 * cfg.seq_len * d * d                         # bilinear map
@@ -74,7 +76,7 @@ def recsys_embed_bytes_per_sample(cfg: RecConfig, itemsize: int = 4) -> int:
     traffic that makes RMC1/2 and DIN memory-bound in paper Fig. 3)."""
     b = cfg.n_tables * cfg.hotness * cfg.embed_dim * itemsize
     if cfg.has_history:
-        b += (cfg.seq_len + 1) * cfg.embed_dim * itemsize
+        b += (cfg.seq_len + 1) * cfg.item_width * itemsize
     return int(b)
 
 

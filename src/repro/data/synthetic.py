@@ -31,16 +31,23 @@ def recsys_layout(cfg: RecConfig, batch: int, *, n_candidates: int = 0,
     if cfg.n_tables:
         out["sparse"] = ((batch, cfg.n_tables, cfg.hotness), jnp.int32)
     if cfg.has_history:
-        out["history"] = ((batch, cfg.seq_len), jnp.int32)
+        ids = _behavior_ids(cfg)
+        out["history"] = ((batch, cfg.seq_len) + ids, jnp.int32)
         out["hist_mask"] = ((batch, cfg.seq_len), jnp.bool_)
         if n_candidates == 0:
-            out["target"] = ((batch,), jnp.int32)
+            out["target"] = ((batch,) + ids, jnp.int32)
     if n_candidates:
         out["candidates"] = ((batch, n_candidates), jnp.int32)
     if with_label and not n_candidates:
         shape = (batch,) if cfg.n_tasks == 1 else (batch, cfg.n_tasks)
         out["label"] = (shape, jnp.float32)
     return out
+
+
+def _behavior_ids(cfg: RecConfig) -> tuple:
+    """Trailing axis of one behavior's ids: its K id fields (DIEN's item
+    and category), none where a behavior is one item id."""
+    return (cfg.item_fields,) if cfg.item_fields else ()
 
 
 def recsys_specs(cfg: RecConfig, batch: int, **kw) -> dict[str, Spec]:
@@ -67,14 +74,15 @@ def recsys_batch(rng: np.random.Generator, cfg: RecConfig, batch: int, *,
         logit += ((sparse.sum(axis=(1, 2)) % 7) - 3) * 0.3
         out["sparse"] = sparse.astype(np.int32)
     if cfg.has_history:
-        hist = _zipf_ids(rng, (batch, cfg.seq_len), cfg.item_vocab)
+        ids = _behavior_ids(cfg)
+        hist = _zipf_ids(rng, (batch, cfg.seq_len) + ids, cfg.item_vocab)
         out["history"] = hist.astype(np.int32)
         lengths = rng.integers(1, cfg.seq_len + 1, size=batch)
         out["hist_mask"] = (np.arange(cfg.seq_len)[None] < lengths[:, None])
         if n_candidates == 0:
-            tgt = _zipf_ids(rng, (batch,), cfg.item_vocab).astype(np.int32)
+            tgt = _zipf_ids(rng, (batch,) + ids, cfg.item_vocab).astype(np.int32)
             out["target"] = tgt
-            logit += ((tgt % 5) - 2) * 0.2
+            logit += ((tgt.reshape(batch, -1)[:, 0] % 5) - 2) * 0.2
     if n_candidates:
         out["candidates"] = _zipf_ids(
             rng, (batch, n_candidates), cfg.item_vocab or cfg.vocab).astype(np.int32)

@@ -96,7 +96,8 @@ def recsys_param_pspecs(params: PyTree, *, model_axis_size: int = 16) -> PyTree:
     placement for narrow tables (xdeepfm's dim-10 tables)."""
     def one(path, leaf):
         p = _path_str(path)
-        if "['tables']" in p and leaf.ndim == 3:      # (F, V, D)
+        if ("['tables']" in p or "['item_tables']" in p) \
+                and leaf.ndim == 3:                   # (F, V, D)
             if leaf.shape[2] % model_axis_size == 0:
                 return P(None, None, "model")
             return P(None, "model", None)             # row-sharded

@@ -25,28 +25,43 @@ def init_table(rng, vocab: int, dim: int, *, dtype=jnp.float32, scale: float | N
 # ---------------------------------------------------------- 128-lane packing
 
 LANES = 128
+SUBLANES = 8
 
 
 def pack_rows(table: jax.Array) -> jax.Array:
-    """``(..., V, D)`` → ``(..., ceil(V/p), 128)`` with ``p = 128 // D``
-    rows of the table side by side in each packed row: a row-major
-    reshape, zero rows appended where ``V % p``.  A table whose width is
-    128 or more, or does not divide 128, is returned as it is.
+    """``(..., V, D)`` → ``(..., Vp, 128)`` with ``p = 128 // D`` rows of
+    the table side by side in each packed row, at lanes ``j * D`` to
+    ``(j + 1) * D``: a row-major reshape, zero rows appended to make ``Vp
+    = ceil(V / p)``.  A width that does not divide 128 leaves zero lanes
+    (2 of 128 at ``D = 18``), and its ``Vp`` is rounded up to a whole
+    number of 8-row tiles.  A table whose width is 128 or more is returned
+    as it is.
 
     Why: on the TPU a ``(V, D)`` float32 table with ``D < 128`` is laid out
     with the row axis minor (``{0,1:T(8,128)}``), so as not to pad ``D`` to
     128 lanes.  Each (8, 128) tile then holds 8 values of 128 different
-    rows, one row lies across ``D / 8`` tiles (four 4-KB tiles for
+    rows, one row lies across ``ceil(D / 8)`` tiles (four 4-KB tiles for
     ``D = 32``), and a gather reads all of them.  Packed, the table is
-    row-major with no padding and each lookup reads one contiguous
-    512-byte row."""
+    row-major and each lookup reads one contiguous 512-byte row.  With
+    ``Vp % 8 != 0`` the flat ``(F * Vp, 128)`` view that ``take_rows``
+    gathers from is a copy of every table on every call (at ``D = 18``
+    and 1M rows, 1.17 GB of temporaries); a width that divides 128 keeps
+    the rows it was first packed with, whole tiles at the benchmark's 1M
+    rows (250,000 at ``D = 32``)."""
     *lead, v, d = table.shape
-    if d >= LANES or LANES % d:
+    if d >= LANES:
         return table
     p = LANES // d
-    if v % p:
-        table = jnp.pad(table, [(0, 0)] * len(lead) + [(0, -v % p), (0, 0)])
-    return table.reshape(*lead, -1, LANES)
+    vp = -(-v // p)
+    if p * d < LANES:
+        vp = -(-vp // SUBLANES) * SUBLANES
+    if vp * p > v:
+        table = jnp.pad(table, [(0, 0)] * len(lead) + [(0, vp * p - v), (0, 0)])
+    table = table.reshape(*lead, vp, p * d)
+    if p * d < LANES:
+        table = jnp.pad(table, [(0, 0)] * (len(lead) + 1)
+                        + [(0, LANES - p * d)])
+    return table
 
 
 def take_rows(tables: jax.Array, idx: jax.Array, dim: int,
@@ -58,9 +73,10 @@ def take_rows(tables: jax.Array, idx: jax.Array, dim: int,
 
     Plain tables are read by ``jnp.take`` per table.  Packed ones are read
     by one gather over the stack seen as one ``(F * Vp, 128)`` table, at
-    packed row ``f * Vp + id // p``; compare and select then keep lanes
-    ``(id % p) * dim`` to ``+ dim``, so every value is the stored one, bit
-    for bit (no one-hot contraction, which the TPU would run in bfloat16).
+    packed row ``f * Vp + id // p`` with ``p = 128 // dim``; compare and
+    select then keep lanes ``(id % p) * dim`` to ``+ dim``, so every value
+    is the stored one, bit for bit (no one-hot contraction, which the TPU
+    would run in bfloat16).
     Either way ids outside ``[0, vocab)`` read what ``jnp.take`` reads: an
     id in ``[-vocab, 0)`` counts from the end, any other reads NaN (never
     the zero rows ``pack_rows`` appends).

@@ -8,10 +8,13 @@ bags, a pluggable feature-interaction op, and predict-FC stack(s).
 Batch layout (all dense arrays → shardable under pjit):
     dense      (B, n_dense)            float   — continuous features
     sparse     (B, F, H)               int32   — H lookups per field
-    history    (B, T)                  int32   — behavior sequence (DIN/DIEN/
-                                                 MIND/BERT4Rec)
-    hist_mask  (B, T)                  bool
+    history    (B, T)                  int32   — behavior sequence (DIN/MIND/
+                                                 BERT4Rec)
+               (B, T, K)               int32   — DIEN: K ids per behavior
+                                                 (item, category)
+    hist_mask  (B, T)                  bool    — valid positions, right-padded
     target     (B,)                    int32   — candidate item id
+               (B, K)                  int32   — DIEN: its K ids
     candidates (B, C)                  int32   — retrieval scoring
     label      (B,) / (B, n_tasks)     float
 """
@@ -48,6 +51,9 @@ class RecConfig:
     # sequence models
     seq_len: int = 0
     item_vocab: int = 0
+    item_fields: int = 0                 # ids per behavior, one table each (DIEN:
+                                         # item, category); 0: one item id
+                                         # and no field axis
     # CIN (xDeepFM)
     cin_layers: Sequence[int] = ()
     dnn_widths: Sequence[int] = ()
@@ -70,6 +76,12 @@ class RecConfig:
     def has_history(self) -> bool:
         return self.interaction in ("din", "dien", "mind", "bidir-seq")
 
+    @property
+    def item_width(self) -> int:
+        """Width of one behavior's embedding: its ids' rows side by side
+        (one row where a behavior is one item id)."""
+        return max(self.item_fields, 1) * self.embed_dim
+
 
 # ------------------------------------------------------------------- init
 
@@ -83,7 +95,13 @@ def init(rng, cfg: RecConfig):
         keys = jax.random.split(rs[0], cfg.n_tables)
         p["tables"] = jnp.stack(
             [emb_lib.init_table(k, cfg.vocab, cfg.embed_dim, dtype=dt) for k in keys])
-    if cfg.has_history or cfg.interaction == "bidir-seq":
+    if cfg.item_fields:
+        # (K, V, D): one table per id field of a behavior
+        keys = jax.random.split(rs[1], cfg.item_fields)
+        p["item_tables"] = jnp.stack(
+            [emb_lib.init_table(k, cfg.item_vocab, cfg.embed_dim, dtype=dt)
+             for k in keys])
+    elif cfg.has_history:
         p["item_table"] = emb_lib.init_table(rs[1], cfg.item_vocab, cfg.embed_dim, dtype=dt)
     if cfg.dense_fc:
         p["dense_mlp"] = init_mlp(rs[2], cfg.n_dense, cfg.dense_fc, dtype=dt)
@@ -105,9 +123,11 @@ def init(rng, cfg: RecConfig):
     elif cfg.interaction == "din":
         p["din"] = ix.init_din_attention(rs[7], cfg.embed_dim, dtype=dt)
     elif cfg.interaction == "dien":
-        p["gru"] = rnn_lib.init_gru(rs[8], cfg.embed_dim, cfg.gru_hidden, dtype=dt)
+        p["gru"] = rnn_lib.init_gru(rs[8], cfg.item_width, cfg.gru_hidden, dtype=dt)
         p["augru"] = rnn_lib.init_gru(rs[9], cfg.gru_hidden, cfg.gru_hidden, dtype=dt)
-        p["att_score"] = init_linear(rs[10], cfg.gru_hidden + cfg.embed_dim, 1, dtype=dt)
+        # the bilinear attention's W: a_t ∝ exp(h_t W e_a)
+        p["att"] = init_linear(rs[10], cfg.gru_hidden, cfg.item_width,
+                               bias=False, dtype=dt)
     elif cfg.interaction == "mind":
         p["capsule"] = ix.init_capsule_routing(rs[11], cfg.embed_dim, dtype=dt)
     elif cfg.interaction == "bidir-seq":
@@ -156,8 +176,8 @@ def _interaction_dim(cfg: RecConfig) -> int:
         return cfg.n_tables * cfg.n_heads * cfg.d_attn
     if cfg.interaction == "din":                      # pooled hist + target + tables
         return (2 + cfg.n_tables) * cfg.embed_dim
-    if cfg.interaction == "dien":
-        return cfg.gru_hidden + (1 + cfg.n_tables) * cfg.embed_dim
+    if cfg.interaction == "dien":                     # h'_T ⊕ target ⊕ fields
+        return cfg.gru_hidden + cfg.item_width + cfg.n_tables * cfg.embed_dim
     if cfg.interaction == "mind":
         return 2 * cfg.embed_dim                      # interest ⊕ target
     if cfg.interaction == "bidir-seq":
@@ -192,15 +212,20 @@ def forward(params, cfg: RecConfig, batch: dict) -> jax.Array:
                 dense_out = mlp(params["dense_mlp"], dense_out, act="relu",
                                 final_act="relu")
 
-    emb = None
-    if cfg.n_tables:
-        with jax.named_scope("embedding_gather"):
+    emb = seq = None
+    with jax.named_scope("embedding_gather"):
+        if cfg.n_tables:
             emb = _sparse_pooled(params, cfg, batch["sparse"])
+        if cfg.interaction == "dien":
+            seq = _behaviors(params, cfg, batch)
 
     if cfg.interaction == "cin":                      # carries its own heads
         return _xdeepfm_forward(params, cfg, emb, batch)
     with jax.named_scope("interaction"):
-        z = _interact(params, cfg, emb, dense_out, batch)
+        if cfg.interaction == "dien":
+            z = _dien_interest(params, cfg, emb, seq, batch.get("hist_mask"))
+        else:
+            z = _interact(params, cfg, emb, dense_out, batch)
     with jax.named_scope("top_mlp"):
         outs = [mlp(pp, z, act="relu") for pp in params["predict"]]
     out = jnp.concatenate(outs, axis=-1) if cfg.n_tasks > 1 else outs[0]
@@ -243,20 +268,6 @@ def _interact(params, cfg: RecConfig, emb, dense_out, batch):
         if emb is not None:
             parts.append(emb.reshape(emb.shape[0], -1))
         z = jnp.concatenate(parts, axis=-1)
-    elif it == "dien":
-        hist = jnp.take(params["item_table"], batch["history"], axis=0)
-        tgt = jnp.take(params["item_table"], batch["target"], axis=0)
-        hs = rnn_lib.gru(params["gru"], hist)                        # (B, T, Hg)
-        att_in = jnp.concatenate(
-            [hs, jnp.broadcast_to(tgt[:, None], hist.shape[:2] + (cfg.embed_dim,))], -1)
-        scores = jax.nn.sigmoid(linear(params["att_score"], att_in))[..., 0]
-        if "hist_mask" in batch:
-            scores = scores * batch["hist_mask"].astype(scores.dtype)
-        hT = rnn_lib.augru(params["augru"], hs, scores)              # (B, Hg)
-        parts = [hT, tgt]
-        if emb is not None:
-            parts.append(emb.reshape(emb.shape[0], -1))
-        z = jnp.concatenate(parts, axis=-1)
     elif it == "mind":
         caps = _mind_interests(params, cfg, batch)                   # (B, K, D)
         tgt = jnp.take(params["item_table"], batch["target"], axis=0)
@@ -274,6 +285,57 @@ def _interact(params, cfg: RecConfig, emb, dense_out, batch):
     else:
         raise ValueError(it)
     return z
+
+
+def _behaviors(params, cfg: RecConfig, batch: dict):
+    """DIEN's behavior and target embeddings, each the rows of its K ids
+    side by side (item ⊕ category): history (B, T, K) and target (B, K)
+    ids → (B, T, K·D) and (B, K·D), read by one lookup of the packed or
+    plain ``item_tables``."""
+    ids = jnp.concatenate([batch["history"], batch["target"][:, None]], 1)
+    b, t1, k = ids.shape
+    rows = emb_lib.take_rows(params["item_tables"], jnp.swapaxes(ids, 1, 2),
+                             cfg.embed_dim, cfg.item_vocab)   # (B, K, T+1, D)
+    rows = jnp.swapaxes(rows, 1, 2).reshape(b, t1, k * cfg.embed_dim)
+    return rows[:, :-1], rows[:, -1]
+
+
+def _dien_interest(params, cfg: RecConfig, emb, seq, mask):
+    """DIEN's interest path (Zhou et al., arXiv:1809.03672, §4) → the head's
+    input ``concat(h'_T, e_a, field embeddings)``.
+
+    * interest extractor: a GRU over the behaviors ``e_t`` → ``h_t``
+      (``layers/rnn.py`` has the paper's gate convention);
+    * attention: ``a_t = exp(h_t W e_a) / Σ_j exp(h_j W e_a)``, a float32
+      softmax over each row's valid positions, ``a_t = 0`` on padding
+      (histories are right-padded; ``mask`` None: every position valid);
+    * interest evolving: the AUGRU over all T steps, so ``h'_T`` is the
+      state after the last valid step.
+
+    Departures from the paper, for serving: the head (``top_mlp``) takes
+    ReLU where the paper takes PReLU/Dice (Dice's batch statistics have no
+    serving meaning) and gives one logit where Table I's head is a 2-way
+    softmax (whose click probability is σ of the two logits' difference);
+    no auxiliary loss (it exists only in training); the public code's
+    extra head inputs (the history's sum and its product with the target)
+    are not taken."""
+    hist, tgt = seq
+    with jax.named_scope("interest_extractor"):
+        hs = rnn_lib.gru(params["gru"], hist)                        # (B, T, H)
+    with jax.named_scope("interest_attention"):
+        q = jnp.einsum("hw,bw->bh", params["att"]["w"], tgt)          # W e_a
+        s = jnp.einsum("bth,bh->bt", hs, q).astype(jnp.float32)
+        if mask is not None:
+            s = jnp.where(mask, s, -jnp.inf)
+        att = jax.nn.softmax(s, axis=-1)
+        if mask is not None:
+            att = jnp.where(mask, att, 0.0)
+    with jax.named_scope("interest_evolution"):
+        hT = rnn_lib.augru(params["augru"], hs, att.astype(hs.dtype))  # (B, H)
+    parts = [hT, tgt]
+    if emb is not None:
+        parts.append(emb.reshape(emb.shape[0], -1))
+    return jnp.concatenate(parts, axis=-1)
 
 
 def _xdeepfm_forward(params, cfg, emb, batch):

@@ -138,6 +138,47 @@ def test_dlrm_rmc1_named_scopes_change_no_program(one_chip, monkeypatch,
     assert program(scoped) == program(plain)
 
 
+@pytest.mark.parametrize("bucket", [1, 64, 256])
+def test_dien_served_forward_compiles(one_chip, bucket):
+    """DIEN at its published widths, served as ``recsys_model`` holds it:
+    all ten 18-wide tables (eight fields, item and category) packed seven
+    rows to a 128-lane row; every lookup in the gather scope, none with a
+    bit-packed index; the GRU, the attention and the AUGRU each in its
+    named scope inside ``interaction``, the GRU and the AUGRU each one
+    Pallas kernel (``kernels/gru.py``) with no while loop left."""
+    cfg = configs.get("dien").config
+    params = _on(one_chip, served_param_shapes(cfg))
+    assert params["tables"].shape == (8, 142_864, 128)
+    assert params["item_tables"].shape == (2, 142_864, 128)
+    batch = _on(one_chip, syn.recsys_specs(cfg, bucket, with_label=False))
+    assert batch["history"].shape == (bucket, 100, 2)
+    compiled = served_forward("tpu").lower(params, cfg, batch).compile()
+    mem = compiled.memory_analysis()
+    # 731,463,680 bytes of packed tables, nothing else near them
+    assert 10 * 142_864 * 128 * 4 <= mem.argument_size_in_bytes < 7.4e8
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    hlo = compiled.as_text()
+    assert "cross_program_prefetch" not in hlo
+    assert not _bitpacked_gathers(hlo)
+    gathers = [line for line in hlo.splitlines()
+               if " gather(" in line and "slice_sizes={1,128}" in line]
+    assert gathers and all("/embedding_gather/" in line for line in gathers)
+    assert not [line for line in hlo.splitlines() if " gather(" in line
+                and "slice_sizes={1,18}" in line]
+    for scope in ("interest_extractor", "interest_attention",
+                  "interest_evolution"):
+        assert f"jit(forward)/interaction/{scope}/" in hlo, scope
+    # each recurrence is one Pallas kernel in its scope, not a scan
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    for scope, line in zip(("interest_extractor", "interest_evolution"),
+                           kernels):
+        assert f"/interaction/{scope}/" in line, scope
+    assert " while(" not in hlo
+
+
 def test_dot_interaction_kernel_compiles(one_chip):
     feats = jax.ShapeDtypeStruct((1024, 11, 128), jnp.float32,
                                  sharding=one_chip)

@@ -99,3 +99,62 @@ def test_flash_decode_pos_zero_vs_one():
     got = ops.decode_attention(q, k, v, jnp.array([1]), interpret=True)
     np.testing.assert_allclose(np.asarray(got[0, 0]), np.asarray(v[0, 0, 0]),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("b,t,d,h", [
+    (3, 7, 36, 36),       # a bucket under 8 rows, one chunk of 7 steps
+    (64, 100, 36, 36),    # DIEN at bucket 64: four chunks of 25 steps
+    (256, 10, 20, 36),    # eight rows of steps per chunk: chunks of 8 → 5
+    (5, 4, 8, 130),       # a state wider than 128 lanes
+])
+def test_gru_kernel_matches_the_scan(b, t, d, h):
+    """The TPU branch of ``layers.rnn`` (padded, time-major, the Pallas
+    kernel interpreted) against the scan that defines the equations: the
+    GRU's every state and the AUGRU's last, from a nonzero state, with
+    nonzero biases and attention scores in (0, 1), at ``highest``."""
+    from repro.layers import rnn
+    ks = jax.random.split(jax.random.fold_in(KEY, b * t), 5)
+    p = rnn.init_gru(ks[0], d, h)
+    p["wi"]["b"] = jax.random.normal(ks[1], p["wi"]["b"].shape)
+    xs = jax.random.normal(ks[2], (b, t, d))
+    h0 = 0.5 * jax.random.normal(ks[3], (b, h))
+    att = jax.random.uniform(ks[4], (b, t))
+    with jax.default_matmul_precision("highest"):
+        got = rnn._gru_tpu(p, xs, h0, interpret=True)
+        want = rnn._gru_scan(p, xs, h0)
+        got_t = rnn._augru_tpu(p, xs, att, h0, interpret=True)
+        want_t = rnn._augru_scan(p, xs, att, h0)
+    assert got.shape == (b, t, h) and got_t.shape == (b, h)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_t), np.asarray(want_t),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_gru_kernel_chunks_divide_the_steps():
+    from repro.kernels import gru
+    assert gru.chunk_steps(100, 64) == 25
+    assert gru.chunk_steps(100, 256) == 5
+    assert gru.chunk_steps(100, 8) == 100
+    assert gru.chunk_steps(7, 2048) == 1
+    assert gru.chunk_steps(97, 64) == 1
+
+
+def test_gru_gradient_is_the_scans():
+    """The kernel has no gradient of its own: ``rnn.gru``/``rnn.augru``
+    differentiate as the scan does."""
+    from repro.layers import rnn
+    p = {"gru": rnn.init_gru(KEY, 6, 4),
+         "augru": rnn.init_gru(jax.random.fold_in(KEY, 1), 4, 4)}
+    xs = jax.random.normal(KEY, (2, 3, 6))
+    att = jnp.full((2, 3), 0.5)
+    h0 = jnp.zeros((2, 4))
+
+    def loss(gru, augru):
+        return lambda p: augru(p["augru"], gru(p["gru"], xs, h0), att,
+                               h0).sum()
+    got = jax.grad(loss(rnn.gru, rnn.augru))(p)
+    want = jax.grad(loss(rnn._gru_scan, rnn._augru_scan))(p)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6)

@@ -153,10 +153,28 @@ def test_rope_preserves_norm_and_relative_phase():
 
 
 def test_gru_matches_manual_step():
+    """The paper's convention (DIEN §4): the update gate weights the new
+    candidate, h_t = (1 - u) h_{t-1} + u h̃."""
     p = R.init_gru(KEY, 4, 6)
+    p["wi"]["b"] = jax.random.normal(jax.random.PRNGKey(3), (18,))
     xs = jax.random.normal(KEY, (2, 5, 4))
     hs = R.gru(p, xs)
     assert hs.shape == (2, 5, 6)
+    wi, b, wh = (np.asarray(p["wi"]["w"], np.float64), np.asarray(p["wi"]["b"]),
+                 np.asarray(p["wh"]["w"], np.float64))
+    sig = lambda v: 1 / (1 + np.exp(-v))  # noqa: E731
+    h = np.zeros((2, 6))
+    for t in range(5):
+        x = np.asarray(xs[:, t], np.float64)
+        r = sig(x @ wi[:, :6] + b[:6] + h @ wh[:, :6])
+        u = sig(x @ wi[:, 6:12] + b[6:12] + h @ wh[:, 6:12])
+        c = np.tanh(x @ wi[:, 12:] + b[12:] + r * (h @ wh[:, 12:]))
+        h = (1 - u) * h + u * c
+        np.testing.assert_allclose(np.asarray(hs[:, t]), h, rtol=1e-5,
+                                   atol=1e-5)
+    # AUGRU with full attention is the GRU; with zero attention, frozen
+    h_full = R.augru(p, xs, jnp.ones((2, 5)))
+    np.testing.assert_allclose(np.asarray(h_full), h, rtol=1e-5, atol=1e-5)
     # AUGRU with zero attention == frozen state
     h_frozen = R.augru(p, xs, jnp.zeros((2, 5)))
     np.testing.assert_allclose(np.asarray(h_frozen), np.zeros((2, 6)), atol=1e-6)

@@ -69,6 +69,9 @@ def test_recsys_bulk_forward_matches_direct():
 
 
 RECSYS_SCOPES = ("embedding_gather", "bottom_mlp", "interaction", "top_mlp")
+DIEN_SCOPES = ("embedding_gather", "interaction", "top_mlp",
+               "interaction/interest_extractor", "interaction/interest_attention",
+               "interaction/interest_evolution")
 
 
 def without_metadata(hlo: str) -> str:
@@ -78,12 +81,16 @@ def without_metadata(hlo: str) -> str:
     return re.sub(r", metadata=\{[^}]*\}", "", body)
 
 
+@pytest.mark.parametrize("arch, scopes", [("dlrm-rmc1", RECSYS_SCOPES),
+                                          ("dien", DIEN_SCOPES)])
 @pytest.mark.parametrize("bucket", [1, 64])
-def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket):
+def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket,
+                                                    arch, scopes):
     """The served forward's HLO names the gather, the bottom MLP, the
-    interaction and the top MLP; without the scopes XLA compiles the same
+    interaction and the top MLP (DIEN: its GRU, attention and AUGRU
+    inside the interaction); without the scopes XLA compiles the same
     program."""
-    cfg = configs.get("dlrm-rmc1").smoke_config
+    cfg = configs.get(arch).smoke_config
 
     def compiled() -> str:
         jax.clear_caches()
@@ -94,32 +101,44 @@ def test_recsys_forward_scopes_change_only_metadata(monkeypatch, bucket):
 
     scoped = compiled()
     op_names = re.findall(r'op_name="([^"]*)"', scoped)
-    for scope in RECSYS_SCOPES:
+    for scope in scopes:
         assert any(f"jit(forward)/{scope}/" in n for n in op_names), scope
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     plain = compiled()
-    assert not any(f"/{scope}/" in plain for scope in RECSYS_SCOPES)
+    assert not any(f"/{scope}/" in plain for scope in scopes)
     assert without_metadata(scoped) == without_metadata(plain)
 
 
 @pytest.mark.parametrize("pooling", ["sum", "mean"])
 @pytest.mark.parametrize("dim,vocab", [(8, 1000), (16, 1001), (32, 1003),
-                                       (64, 999), (10, 1000), (128, 1001)])
+                                       (64, 999), (10, 1000), (18, 1000),
+                                       (18, 1001), (128, 1001)])
 def test_packed_lookup_matches_plain(dim, vocab, pooling):
     """Tables packed into 128-lane rows give back what ``jnp.take`` gives
     on the plain tables, bit for bit and NaN for NaN: ids 0 and V-1, a
     vocabulary that is no multiple of the pack, and the ids ``jnp.take``
     wraps (-1, -V) or fills (V, which lies in the appended zero rows,
-    -V-1, the int32 extremes).  A width that does not divide 128, or fills
-    it, stays as it is; the pooled lookup agrees to float32 rounding."""
+    -V-1, the int32 extremes).  A width under 128 packs ``128 // D`` rows
+    to a row; one that does not divide 128 (D = 10 and 18) leaves the
+    lanes over zero and packs a whole number of 8-row tiles; a width that
+    fills 128 stays as it is; the pooled lookup agrees to float32
+    rounding."""
     n_tables, batch, hot = 3, 5, 7
     table = jax.random.normal(KEY, (n_tables, vocab, dim))
     packed = emb_lib.pack_rows(table)
-    p = 128 // dim if 128 % dim == 0 and dim < 128 else 1
-    want_shape = ((n_tables, -(-vocab // p), 128) if p > 1
+    p = 128 // dim
+    vp = -(-vocab // p)
+    if 128 % dim:
+        vp = (vp + 7) // 8 * 8
+    want_shape = ((n_tables, vp, 128) if dim < 128
                   else (n_tables, vocab, dim))
     assert packed.shape == want_shape
+    if dim < 128:
+        rows = np.asarray(packed)[:, :, :p * dim].reshape(n_tables, -1, dim)
+        np.testing.assert_array_equal(rows[:, :vocab], np.asarray(table))
+        assert not rows[:, vocab:].any()
+        assert not np.asarray(packed)[:, :, p * dim:].any()
     sparse = jax.random.randint(jax.random.PRNGKey(1),
                                 (batch, n_tables, hot), 0, vocab)
     edge = [0, vocab - 1, -1, -vocab, vocab, -vocab - 1,
@@ -138,6 +157,65 @@ def test_packed_lookup_matches_plain(dim, vocab, pooling):
         np.asarray(recsys._sparse_pooled({"tables": packed}, cfg, sparse)),
         np.asarray(recsys._sparse_pooled({"tables": table}, cfg, sparse)),
         rtol=1e-6, atol=1e-7)
+
+
+# recorded from the tree before tables of any width under 128 lanes were
+# packed: a (3, 1003, 32) table from PRNGKey(5) and ids from PRNGKey(6)
+# over [-1010, 1010); sha256 of the arrays' bytes
+PINNED_32 = {
+    "packed": "75822275d22cdc0a2a1bf5c765756bba917250b37a7ddd1e3d931150bdd94b92",
+    "rows": "7f2657982b302b176606be7edd17981f1991759553aca953f2c462830cab16ea",
+}
+
+
+def test_packing_32_wide_tables_is_as_before():
+    """At D = 32 the packed table is the array it was before, and the
+    lookup reads the same values, bad ids included."""
+    import hashlib
+
+    def sha(a):
+        return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()
+    table = jax.random.normal(jax.random.PRNGKey(5), (3, 1003, 32))
+    packed = np.asarray(emb_lib.pack_rows(table))
+    assert packed.shape == (3, 251, 128)
+    assert sha(packed) == PINNED_32["packed"]
+    idx = jax.random.randint(jax.random.PRNGKey(6), (5, 3, 7), -1010, 1010)
+    assert sha(np.asarray(emb_lib.take_rows(packed, idx, 32, 1003))) == \
+        PINNED_32["rows"]
+    whole = jax.random.normal(jax.random.PRNGKey(5), (2, 4 * 8 * 5, 32))
+    np.testing.assert_array_equal(np.asarray(emb_lib.pack_rows(whole)),
+                                  np.asarray(whole).reshape(2, 40, 128))
+
+
+def test_served_dien_packs_every_table():
+    """DIEN at its 18-wide rows is served with its eight field tables and
+    its item and category tables packed seven rows to a 128-lane row,
+    holding the values ``recsys.init`` draws; the served forward reads
+    every row from a packed table (every gather takes whole 128-lane
+    rows) and gives what ``recsys.forward`` gives on the unpacked
+    tables."""
+    smoke = configs.get("dien").smoke_config
+    cfg = dataclasses.replace(smoke, vocab=101, item_vocab=103, embed_dim=18,
+                              gru_hidden=36)
+    apply_fn, make_batch, params = recsys_model(cfg, seed=3, max_rows=16)
+    plain = jax.jit(recsys.init, static_argnums=1)(jax.random.PRNGKey(3),
+                                                   cfg)
+    assert plain["item_tables"].shape == (2, 103, 18)
+    assert params["tables"].shape == (cfg.n_tables, 16, 128)
+    assert params["item_tables"].shape == (2, 16, 128)
+    shapes = served_param_shapes(cfg)
+    for k in ("tables", "item_tables"):
+        assert shapes[k].shape == params[k].shape
+        np.testing.assert_array_equal(np.asarray(params[k]),
+                                      np.asarray(emb_lib.pack_rows(plain[k])))
+    batch = make_batch(16, 0)
+    assert batch["history"].shape == (16, cfg.seq_len, 2)
+    np.testing.assert_allclose(np.asarray(apply_fn(batch)),
+                               np.asarray(recsys.forward(plain, cfg, batch)),
+                               rtol=1e-5, atol=1e-5)
+    hlo = served_forward("cpu").lower(params, cfg, batch).compile().as_text()
+    gathers = re.findall(r" gather\(.*slice_sizes=\{([0-9,]+)\}", hlo)
+    assert gathers and set(gathers) == {"1,128"}
 
 
 def test_served_model_packs_its_tables():
