@@ -106,7 +106,8 @@ def calibrate_device(apply_fn: Callable[[dict], object],
     the thread-wake latency a solo round-trip pays on every request (a
     several-hundred-µs overestimate for sub-ms models) while still
     including everything a steady-state request pays — ``pad_batch``,
-    host→device transfer, dispatch, compute.  The returned curve is what
+    host→device transfer, dispatch, compute — with the worker's second
+    request in flight as in service.  The returned curve is what
     a simulated twin of the live node feeds the fast engine (with
     ``request_overhead_s = 0``, the overhead being folded in), closing
     the sim-vs-real calibration loop.  The first burst per bucket absorbs

@@ -1,10 +1,16 @@
 """Live serving runtime: the real-execution counterpart of the simulator.
 
-Queries → split into requests of ≤ batch_size → FIFO queue → worker threads
-run the jitted model (bucketed shapes) → query completes when its last
-request lands.  An online DeepRecSched controller periodically hill-climbs
-the batch-size knob using the measured p95 over a sliding window — the
-"deployed in production" form of the offline tuner (paper §VI-B).
+Queries → split into requests of ≤ batch_size → FIFO queue → workers run
+the jitted model (bucketed shapes) → query completes when its last request
+lands.  Each worker is two threads: a dispatcher that pads and dispatches
+its requests, and a completer that waits for each result in dispatch order
+and does the bookkeeping, with up to ``DEPTH`` of its requests dispatched
+and not yet complete.  So the host dispatches request k+1 while the device
+runs request k; where ``apply_fn`` returns only when its result is ready,
+the completer's wait returns at once.  An online DeepRecSched controller
+periodically hill-climbs the batch-size knob using the measured p95 over a
+sliding window — the "deployed in production" form of the offline tuner
+(paper §VI-B).
 
 This runs the actual JAX models on this host; the simulator covers at-scale
 what one machine cannot.
@@ -15,11 +21,12 @@ Each request is a row of the runtime's request log
 set of spans on the device trace's clock, each carrying the request's
 ``rid``, ``qid``, ``bucket`` and ``rows`` where known:
 
-  ``runtime.dequeue``      a worker blocked on the queue (idle)
-  ``runtime.pad``          ``pad_batch`` to the request's bucket
+  ``runtime.dequeue``      a dispatcher blocked on the queue (idle)
+  ``runtime.pad``          ``pad_batch`` to the request's bucket (dispatcher)
   ``runtime.dispatch``     the ``apply_fn`` call: enqueue, host-to-device copy
-  ``runtime.device_wait``  ``block_until_ready`` on its result
-  ``runtime.complete``     the bookkeeping under the lock
+                           (dispatcher)
+  ``runtime.device_wait``  ``block_until_ready`` on its result (completer)
+  ``runtime.complete``     the bookkeeping under the lock (completer)
   ``feeder.release``       ``PacedFeeder`` releasing one query (``qid``)
 
 The runtime also installs the process's garbage-collection pause counter
@@ -40,6 +47,10 @@ from repro.serve import recorder
 from repro.serve.batching import bucket_for, pad_batch
 from repro.serve.recorder import span
 
+# requests a worker keeps dispatched and not yet complete: the least depth
+# that hides one request's wait behind the next one's host work
+DEPTH = 2
+
 
 @dataclasses.dataclass
 class _Request:
@@ -47,6 +58,21 @@ class _Request:
     qid: int
     batch: dict
     size: int
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A request from its pick-up to its completion, handed from a worker's
+    dispatcher to its completer; NaN: a stage not reached."""
+    req: _Request
+    worker: int
+    bucket: int
+    ids: dict                 # the request's span stats
+    t_pick: float
+    t_pad: float = float("nan")
+    t_disp: float = float("nan")
+    out: object = None
+    error: str | None = None
 
 
 @dataclasses.dataclass
@@ -66,7 +92,10 @@ class QueryRecord:
 
 
 class ServingRuntime:
-    """n_workers threads over a shared request queue."""
+    """n_workers workers over a shared request queue, each a dispatcher
+    and a completer thread with up to ``DEPTH`` requests in flight."""
+
+    _JOIN_S = 5.0             # shutdown's wait for the workers' threads
 
     def __init__(self, apply_fn: Callable[[dict], object], *,
                  n_workers: int = 2, batch_size: int = 64,
@@ -82,13 +111,23 @@ class ServingRuntime:
         self._fresh_done: list[QueryRecord] = []
         self._done_log: list[QueryRecord] = []
         self._stop = threading.Event()
+        # picked up and not yet complete, by rid: whoever takes a request
+        # out (its completer, or shutdown) closes its row
+        self._in_flight: dict[int, _InFlight] = {}
         self._log = recorder.request_log()
         recorder.pauses()
-        self._workers = [threading.Thread(target=self._worker, args=(w,),
-                                          daemon=True)
-                         for w in range(n_workers)]
-        for w in self._workers:
-            w.start()
+        self._threads = []
+        for w in range(n_workers):
+            slots = threading.BoundedSemaphore(DEPTH)
+            handoff: queue.SimpleQueue = queue.SimpleQueue()
+            self._threads += [
+                threading.Thread(target=self._dispatcher,
+                                 args=(w, slots, handoff), daemon=True),
+                threading.Thread(target=self._completer,
+                                 args=(w, slots, handoff), daemon=True)]
+        self._n_workers = n_workers
+        for t in self._threads:
+            t.start()
 
     # ---------------------------------------------------------------- api
 
@@ -124,14 +163,22 @@ class ServingRuntime:
         raise TimeoutError("serving queue did not drain")
 
     def shutdown(self) -> None:
-        """Stop the workers; requests still queued are abandoned, and their
-        log rows closed as never picked up."""
+        """Stop the workers.  Requests in flight are completed if their
+        results arrive within ``_JOIN_S``, else their log rows are closed
+        with no ``ready`` stamp; requests still queued are abandoned, and
+        their rows closed as never picked up."""
         self._stop.set()
-        for _ in self._workers:
+        for _ in range(self._n_workers):
             self._q.put(None)
-        for w in self._workers:
-            w.join(timeout=5)
+        deadline = time.monotonic() + self._JOIN_S
+        for t in self._threads:
+            t.join(timeout=max(deadline - time.monotonic(), 0.0))
         nan = float("nan")
+        with self._lock:
+            stuck, self._in_flight = self._in_flight, {}
+        for f in stuck.values():
+            self._log.close(f.req.rid, f.bucket, f.worker, f.t_pick,
+                            f.t_pad, f.t_disp, nan, time.monotonic())
         while True:
             try:
                 req = self._q.get_nowait()
@@ -203,53 +250,85 @@ class ServingRuntime:
 
     # ------------------------------------------------------------- worker
 
-    def _worker(self, w: int) -> None:
+    def _dispatcher(self, w: int, slots: threading.BoundedSemaphore,
+                    handoff: queue.SimpleQueue) -> None:
+        """Worker ``w``'s first thread: takes a slot, then a request, pads
+        and dispatches it, and hands it to the completer.  A failed pad
+        or dispatch is handed on with its error, to be completed without
+        a wait."""
+        try:
+            while True:
+                slots.acquire()        # released by the completer
+                if self._stop.is_set():
+                    return
+                with span("runtime.dequeue"):
+                    req = self._q.get()
+                t_pick = time.monotonic()
+                if req is None:
+                    return
+                bucket = bucket_for(req.size, self.max_bucket)
+                f = _InFlight(req, w, bucket,
+                              {"rid": req.rid, "qid": req.qid,
+                               "bucket": bucket, "rows": req.size}, t_pick)
+                with self._lock:
+                    self._in_flight[req.rid] = f
+                try:
+                    with span("runtime.pad", f.ids):
+                        padded = pad_batch(req.batch, bucket)
+                    f.t_pad = time.monotonic()
+                    with span("runtime.dispatch", f.ids):
+                        f.out = self._apply(padded)
+                    f.t_disp = time.monotonic()
+                except Exception as e:
+                    # an apply_fn failure must not kill the worker or
+                    # strand the query's _outstanding entry (which would
+                    # deadlock drain()) — complete the query, carry the
+                    # error
+                    f.error = f"{type(e).__name__}: {e}"
+                handoff.put(f)
+        finally:
+            handoff.put(None)          # the completer ends after the rest
+
+    def _completer(self, w: int, slots: threading.BoundedSemaphore,
+                   handoff: queue.SimpleQueue) -> None:
+        """Worker ``w``'s second thread: in dispatch order, waits for each
+        request's result and completes it, then frees its slot."""
         import jax
-        nan = float("nan")
-        while not self._stop.is_set():
-            with span("runtime.dequeue"):
-                req = self._q.get()
-            t_pick = time.monotonic()
-            if req is None:
-                return
-            bucket = bucket_for(req.size, self.max_bucket)
-            ids = {"rid": req.rid, "qid": req.qid, "bucket": bucket,
-                   "rows": req.size}
-            t_pad = t_disp = t_ready = nan
-            err = None
-            try:
-                with span("runtime.pad", ids):
-                    padded = pad_batch(req.batch, bucket)
-                t_pad = time.monotonic()
-                with span("runtime.dispatch", ids):
-                    out = self._apply(padded)
-                t_disp = time.monotonic()
-                with span("runtime.device_wait", ids):
-                    jax.block_until_ready(out)
-                t_ready = time.monotonic()
-            except Exception as e:
-                # an apply_fn failure must not kill the worker thread or
-                # strand the query's _outstanding entry (which would
-                # deadlock drain()) — complete the query, carry the error
-                err = f"{type(e).__name__}: {e}"
-            finally:
-                with span("runtime.complete", ids):
-                    now = time.monotonic()
-                    with self._lock:
-                        rec = self._records[req.qid]
-                        if err is not None and rec.error is None:
-                            rec.error = err
-                        if rec.t_started == 0.0 or t_pick < rec.t_started:
-                            rec.t_started = t_pick
-                        self._outstanding[req.qid] -= 1
-                        if self._outstanding[req.qid] == 0:
-                            del self._outstanding[req.qid]
-                            rec.t_done = now
-                            self._n_done += 1
-                            self._fresh_done.append(rec)
-                            self._done_log.append(rec)
-                    self._log.close(req.rid, bucket, w, t_pick, t_pad,
-                                    t_disp, t_ready, now)
+        while (f := handoff.get()) is not None:
+            t_ready = float("nan")
+            if f.error is None:
+                try:
+                    with span("runtime.device_wait", f.ids):
+                        jax.block_until_ready(f.out)
+                    t_ready = time.monotonic()
+                except Exception as e:
+                    f.error = f"{type(e).__name__}: {e}"
+            f.out = None
+            self._complete(f, t_ready)
+            slots.release()
+
+    def _complete(self, f: _InFlight, t_ready: float) -> None:
+        with span("runtime.complete", f.ids):
+            with self._lock:
+                # stamped under the lock: of a query's requests, the one
+                # that completes it is the last stamped
+                now = time.monotonic()
+                if self._in_flight.pop(f.req.rid, None) is None:
+                    return             # shutdown has closed its row
+                rec = self._records[f.req.qid]
+                if f.error is not None and rec.error is None:
+                    rec.error = f.error
+                if rec.t_started == 0.0 or f.t_pick < rec.t_started:
+                    rec.t_started = f.t_pick
+                self._outstanding[f.req.qid] -= 1
+                if self._outstanding[f.req.qid] == 0:
+                    del self._outstanding[f.req.qid]
+                    rec.t_done = now
+                    self._n_done += 1
+                    self._fresh_done.append(rec)
+                    self._done_log.append(rec)
+            self._log.close(f.req.rid, f.bucket, f.worker, f.t_pick,
+                            f.t_pad, f.t_disp, t_ready, now)
 
 
 class PacedFeeder:

@@ -1,6 +1,8 @@
 """Live serving runtime: split/execute/complete, bucketing, online control,
 the request log and the pause counter."""
 import gc
+import os
+import threading
 import time
 
 import jax
@@ -428,3 +430,220 @@ def test_pause_counter_counts_a_forced_collection():
     full = rows["generation"] == 2
     assert full.sum() == 1
     assert t0 <= rows["start"][full][0] <= rows["end"][full][0] <= t1
+
+
+# ------------------------------------------- two requests in flight per worker
+
+
+class _Held:
+    """A served output whose ``block_until_ready`` waits until the test
+    releases it (or raises ``fail`` then)."""
+
+    def __init__(self, fail=None):
+        self.waited = threading.Event()
+        self.released = threading.Event()
+        self.fail = fail
+
+    def block_until_ready(self):
+        self.waited.set()
+        if not self.released.wait(timeout=10):
+            raise TimeoutError("the test never released this output")
+        if self.fail is not None:
+            raise self.fail
+        return self
+
+
+class _HeldModel:
+    """``apply_fn`` returning a ``_Held`` output per call, in call order;
+    ``fail_at`` maps a call index to the error its dispatch raises."""
+
+    def __init__(self, fail_at=None):
+        self.outs: list[_Held] = []
+        self.fail_at = fail_at or {}
+        self.called = threading.Condition()
+
+    def __call__(self, batch):
+        with self.called:
+            k = len(self.outs)
+            self.outs.append(_Held())
+            self.called.notify_all()
+        if k in self.fail_at:
+            raise self.fail_at[k]
+        return self.outs[k]
+
+    def wait_calls(self, n, timeout=10.0):
+        with self.called:
+            assert self.called.wait_for(lambda: len(self.outs) >= n,
+                                        timeout), f"{len(self.outs)} calls"
+
+
+def _until(cond, timeout=10.0):
+    t0 = time.monotonic()
+    while not cond():
+        assert time.monotonic() - t0 < timeout, "condition never held"
+        time.sleep(0.001)
+
+
+def _submit(rt, sizes):
+    for qid, size in enumerate(sizes):
+        rt.submit(qid, {"x": np.ones((size, 4), np.float32)}, size)
+
+
+def test_the_runtime_dispatches_the_next_request_before_one_is_ready():
+    model = _HeldModel()
+    rt = ServingRuntime(model, n_workers=1, batch_size=1)
+    try:
+        _submit(rt, [1, 1])
+        model.wait_calls(2)               # both out before either is ready
+        assert rt.n_completed == 0
+        for out in model.outs:
+            out.released.set()
+        rt.drain(timeout=10)
+        rows, _ = rt.request_log()
+    finally:
+        rt.shutdown()
+    assert rows["pad"][1] < rows["ready"][0]
+
+
+def test_no_worker_has_more_than_two_requests_in_flight():
+    model = _HeldModel()
+    rt = ServingRuntime(model, n_workers=2, batch_size=1)
+    try:
+        _submit(rt, [1] * 12)
+        for k in range(12):
+            model.wait_calls(min(k + 4, 12))
+            time.sleep(0.02)              # room for a fifth dispatch
+            assert len(model.outs) == min(k + 4, 12)
+            model.outs[k].released.set()
+        rt.drain(timeout=10)
+        rows, _ = rt.request_log()
+    finally:
+        rt.shutdown()
+    for w in (0, 1):
+        mine = rows["worker"] == w
+        pick, ready = rows["pickup"][mine], rows["ready"][mine]
+        order = np.argsort(rows["dispatch"][mine])
+        pick, ready = pick[order], ready[order]
+        # request k + 2 is picked up only once request k is ready
+        assert np.all(pick[2:] > ready[:-2])
+
+
+def test_completions_follow_dispatch_order_and_a_query_ends_with_its_last():
+    model = _HeldModel()
+    rt = ServingRuntime(model, n_workers=1, batch_size=2)
+    try:
+        _submit(rt, [4, 1])               # query 0: 2 requests; query 1: 1
+        model.wait_calls(2)
+        model.outs[1].released.set()      # the later one is ready first
+        time.sleep(0.05)
+        assert rt.n_completed == 0        # its completion waits on the first
+        model.outs[0].released.set()
+        _until(lambda: rt.record(0).t_done > 0)   # both its requests done
+        model.wait_calls(3)
+        assert rt.record(1).t_done == 0
+        model.outs[2].released.set()
+        rt.drain(timeout=10)
+        rows, _ = rt.request_log()
+        done = [r.qid for r in rt.take_completed()]
+        recs = [rt.record(q) for q in (0, 1)]
+    finally:
+        rt.shutdown()
+    assert done == [0, 1]
+    order = np.argsort(rows["dispatch"])
+    assert np.all(np.diff(rows["done"][order]) >= 0)
+    assert np.all(np.diff(rows["ready"][order]) >= 0)
+    for rec in recs:
+        assert rec.t_done == rows["done"][rows["qid"] == rec.qid].max()
+    t = np.stack([rows[k] for k in recorder.RequestLog.STAMPS])
+    assert np.all(np.diff(t, axis=0) >= 0)
+
+
+def test_a_failed_dispatch_completes_its_query_while_another_is_in_flight():
+    model = _HeldModel(fail_at={1: RuntimeError("boom")})
+    rt = ServingRuntime(model, n_workers=1, batch_size=1)
+    try:
+        _submit(rt, [1, 1, 1])
+        model.wait_calls(2)
+        model.outs[0].released.set()
+        model.wait_calls(3)
+        model.outs[2].released.set()
+        rt.drain(timeout=10)
+        rows, _ = rt.request_log()
+        recs = [rt.record(q) for q in range(3)]
+    finally:
+        rt.shutdown()
+    assert "boom" in recs[1].error and recs[1].t_done > 0
+    assert recs[0].error is None and recs[2].error is None
+    assert rt.n_pending == 0
+    assert np.isfinite(rows["pad"][1]) and np.isnan(rows["dispatch"][1])
+    assert np.isnan(rows["ready"][1]) and np.isfinite(rows["done"]).all()
+
+
+def test_an_output_that_fails_its_wait_completes_its_query_with_the_error():
+    model = _HeldModel()
+    rt = ServingRuntime(model, n_workers=1, batch_size=1)
+    try:
+        _submit(rt, [1, 1])
+        model.wait_calls(2)
+        model.outs[0].fail = RuntimeError("device lost")
+        for out in model.outs:
+            out.released.set()
+        rt.drain(timeout=10)
+        rows, _ = rt.request_log()
+        bad, good = rt.record(0), rt.record(1)
+    finally:
+        rt.shutdown()
+    assert "device lost" in bad.error and bad.t_done > 0
+    assert good.error is None and good.t_done > 0
+    assert np.isfinite(rows["dispatch"][0]) and np.isnan(rows["ready"][0])
+    assert np.isfinite(rows["ready"][1]) and np.isfinite(rows["done"]).all()
+
+
+def test_shutdown_closes_the_row_of_a_request_still_in_flight():
+    model = _HeldModel()
+    rt = ServingRuntime(model, n_workers=1, batch_size=1)
+    rt._JOIN_S = 0.3
+    _submit(rt, [1])
+    model.wait_calls(1)
+    _until(lambda: model.outs[0].waited.is_set())   # dispatched, waited on
+    t0 = time.monotonic()
+    rt.shutdown()
+    took = time.monotonic() - t0
+    rows, _ = rt.request_log()
+    assert took < rt._JOIN_S + 2.0
+    assert len(rows["rid"]) == 1 and np.isfinite(rows["dispatch"][0])
+    assert np.isnan(rows["ready"][0]) and np.isfinite(rows["done"][0])
+    # the result arriving later changes neither the row nor the query
+    model.outs[0].released.set()
+    for t in rt._threads:
+        t.join(timeout=5)
+        assert not t.is_alive()
+    again, _ = rt.request_log()
+    assert np.isnan(again["ready"][0]) and again["done"][0] == rows["done"][0]
+    assert rt.n_completed == 0 and rt.record(0).t_done == 0
+
+
+def test_many_workers_complete_every_query_once_under_thread_switching():
+    """More workers than cores, the interpreter switching threads often:
+    each query completes once, when its last request does."""
+    import sys
+    n_workers = (os.cpu_count() or 4) + 1
+    rng = np.random.default_rng(1)
+    sizes = rng.integers(1, 40, 300).tolist()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    rt = ServingRuntime(lambda b: b["x"] * 2.0, n_workers=n_workers,
+                        batch_size=8)
+    try:
+        _submit(rt, sizes)
+        rt.drain(timeout=60)
+        rows, _ = rt.request_log()
+        done = rt.completed_log(0)
+    finally:
+        sys.setswitchinterval(interval)
+        rt.shutdown()
+    assert sorted(r.qid for r in done) == list(range(len(sizes)))
+    assert rt.n_completed == len(sizes) and rt.n_pending == 0
+    assert len(rows["rid"]) == sum(-(-n // 8) for n in sizes)
+    for r in done:
+        assert r.t_done == rows["done"][rows["qid"] == r.qid].max()
