@@ -2,48 +2,29 @@
 one ``NodeBackend`` interface (paper §VII — DeepRecSched deployed "on
 hundreds of machines", validated against real execution).
 
-* ``backend`` — the ``NodeBackend`` contract (submit / advance-to-time /
-  completed-records / capacity weight) plus ``SimNodeBackend``, the numpy
-  fast engine behind it.
-* ``live`` — ``LiveNodeBackend``: real ``ServingRuntime`` instances (jitted
-  JAX models, wall-clock pacing, per-node online controllers) behind the
-  same contract, with device-curve calibration to close the sim-vs-real
-  loop.
-* ``remote`` — ``RemoteNodeBackend``: worker *processes* over localhost
-  sockets (``serve.remote`` wire protocol) behind the same contract, plus
-  the ``WorkerSupervisor`` that spawns, health-checks, and reaps them —
-  real multi-core serving, real ``SIGKILL`` faults, measured boot times.
+* ``backend`` — the ``NodeBackend`` contract, the ``Completions`` batch
+  every engine reports finished queries in, and ``SimNodeBackend``, the
+  numpy fast engine.
+* ``live`` / ``remote`` — real ``ServingRuntime``s (jitted JAX models,
+  wall-clock pacing, device-curve calibration) in this process or in
+  worker processes over localhost sockets, with the ``WorkerSupervisor``
+  that spawns, health-checks and reaps them.
 * ``fleet`` — ``NodeSpec``/``Pool``/``Fleet``: mixed CPU generations and
   accelerator nodes, each pool with its own DeepRecSched knobs.
-* ``router`` — pluggable, backend-agnostic query-routing policies
-  (round-robin, least-outstanding-work, size-aware, Hercules-style
-  heterogeneity-aware with per-tenant affinity).
-* ``traffic`` — diurnal / bursty / multi-tenant arrival scenarios.
-* ``lifecycle`` — the node lifecycle layer: ``NodeState``
-  (BOOTING → SERVING → DRAINING → DEAD, with a transient SUSPECT) owned
-  by a ``FleetController`` that materializes, boots, drains, retires, and
-  kills backends on the shared timeline; ``FleetFaults`` kill plans with
-  re-route, and ``SelfHealPolicy`` auto-restart under a crash-loop
-  budget plus terminate-after-idle for draining nodes.
-* ``chaos`` — deterministic fault injection: ``ChaosPlan`` extends
-  ``FleetFaults`` with hung RPCs, garbled/dropped frames, and slow-start
-  spawns, all scheduled at trace times (``crash_storm`` builds the kill
-  bursts the chaos benchmark gates on).
-* ``autoscaler`` — reactive p95-vs-SLA pool scaling plus the predictive
-  boot-latency-ahead ``PredictiveAutoscaler`` over traffic forecasts,
-  with node-hour accounting, against the ``CapacityLedger`` protocol;
-  ``TelemetrySignal`` swaps the driver-plumbed p95 scalar for the
-  registry's window sketches, and ``DiagnosisPolicy`` wraps any scaler
-  with SLO-breach-diagnosis-matched actions (scale out on queueing
-  saturation, hold on fault recovery, pre-warm on cold capacity) via
-  ``drive_fleet(slo=..., autoscaler=DiagnosisPolicy(...))``.
-* ``cache`` — ``FleetCache``: the fleet-front result cache (sharded
-  LRU/LFU with TTL staleness) that answers popularity-keyed repeats
-  before the router; ``drive_fleet(cache=..., query_keys=...)``.
-* ``cluster_sim`` — ``drive_fleet``, the engine-agnostic shared-timeline
-  driver (plus the event engine per node when faults/contention are
-  enabled); ``OffloadTuning`` turns on the per-node online
-  offload-threshold controller.
+* ``router`` — backend-agnostic routing policies; ``traffic`` — diurnal /
+  bursty / multi-tenant arrival scenarios.
+* ``lifecycle`` — ``FleetController`` owns node state (BOOTING → SERVING
+  → DRAINING → DEAD, with a transient SUSPECT), ``FleetFaults`` kill
+  plans and ``SelfHealPolicy``; ``chaos`` adds hung RPCs, garbled frames
+  and slow starts (``ChaosPlan``).
+* ``cluster_sim`` — ``drive_fleet``, one window loop over any backends,
+  plus ``simulate_fleet`` and ``cluster_max_qps``.  Each opt-in layer is
+  one stage the loop calls at fixed events: telemetry and SLO evaluation
+  (``repro.obs``), the fleet-front cache (``cache.CacheStage`` over
+  ``FleetCache``), online offload tuning (``OffloadTuning``) and
+  autoscaling (``autoscaler.ScalingStage`` over ``Autoscaler``,
+  ``PredictiveAutoscaler`` or ``DiagnosisPolicy``, with
+  ``TelemetrySignal`` reading the registry's window sketches).
 """
 from repro.cluster.autoscaler import (Autoscaler,  # noqa: F401
                                       CapacityLedger, DiagnosisPolicy,

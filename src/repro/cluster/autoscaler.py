@@ -37,12 +37,9 @@ is recorded as a :class:`ScalingEvent` whose ``reason`` names the trigger
 that fired (``"p95"`` / ``"util"`` / ``"forecast"``).
 
 An autoscaler never reaches into engine state: it sees only a
-``CapacityLedger`` — named pools with capacity weights and a ``scale``
-method.  ``fleet.Fleet`` is the canonical ledger; the driver
-(``cluster_sim.drive_fleet``) materializes the corresponding node
-backends — simulated or live — through the fleet lifecycle controller, so
-the same scaling policy governs either engine (and newly ordered nodes
-pay their spec's ``boot_s`` before serving).
+``CapacityLedger`` (``fleet.Fleet``); :class:`ScalingStage` runs it in
+the driver loop, whose lifecycle controller materializes the ledger's
+nodes in either engine (new ones pay their spec's ``boot_s``).
 """
 from __future__ import annotations
 
@@ -53,6 +50,7 @@ from typing import Protocol, Sequence, runtime_checkable
 import numpy as np
 
 from repro.obs.slo import ControlAction
+from repro.obs.telemetry import FleetStage
 
 
 @runtime_checkable
@@ -114,11 +112,6 @@ class TelemetrySignal:
         """Latest window's fleet-latency p95 from the sketch, or None."""
         return self._q("fleet_latency_ms", 0.95)
 
-    def window_queueing_p95_ms(self) -> float | None:
-        """Latest window's p95 executor-queueing component, or None
-        (needs the SLO span folds: ``drive_fleet(slo=...)``)."""
-        return self._q("span_queueing_ms", 0.95)
-
 
 @dataclasses.dataclass
 class Autoscaler:
@@ -135,6 +128,14 @@ class Autoscaler:
 
     def reset(self) -> None:
         self.events, self._cooldown = [], 0
+
+    @property
+    def actions(self) -> tuple:
+        """Diagnosed control actions taken (a plain scaler takes none)."""
+        return ()
+
+    def inform(self, diagnoses, booting: float = 0.0) -> None:
+        """Breach diagnoses of the window (a plain scaler ignores them)."""
 
     def _p95(self, p95_ms: float) -> float:
         """Effective p95 signal: the registry window sketch when a bound
@@ -313,8 +314,9 @@ class DiagnosisPolicy:
     Calm windows delegate wholesale, so outside incidents the policy is
     exactly its wrapped scaler.  Every diagnosed decision is recorded as
     a :class:`~repro.obs.slo.ControlAction` (the driver stitches these
-    into the incident log).  Duck-types the ``Autoscaler`` surface the
-    driver uses (``reset`` / ``observe`` / ``events`` / ``signal``).
+    into the incident log).  Offers the scaler surface the driver uses
+    (``reset`` / ``observe`` / ``inform`` / ``events`` / ``actions`` /
+    ``signal``, the last its wrapped scaler's).
     """
 
     scaler: Autoscaler
@@ -381,3 +383,35 @@ class DiagnosisPolicy:
             return self._act(t_s, d.objective, v, "prewarm", delta)
         delta = s.observe(t_s, p95_ms, offered_qps, fleet)
         return self._act(t_s, d.objective, v, "delegate", delta)
+
+
+class ScalingStage(FleetStage):
+    """Autoscaling in the driver loop: at each boundary the scaler gets
+    the window's breach diagnoses, observes the window and resizes the
+    ledger; its actions are stitched into the SLO engine's incidents
+    and the controller picks up the resize.  An unbound
+    :class:`TelemetrySignal` is bound to the run's ``telemetry``."""
+
+    def __init__(self, scaler, telemetry=None, incidents=None):
+        self.scaler, self.telemetry, self.incidents = \
+            scaler, telemetry, incidents
+
+    def start(self, run) -> None:
+        self.scaler.reset()
+        sig = self.scaler.signal
+        if self.telemetry is not None and sig is not None \
+                and sig.telemetry is None:
+            sig.bind(self.telemetry)
+        self.controller = run.controller
+        self._stitched = 0
+
+    def end_window(self, w) -> None:
+        if w.diagnoses:
+            self.scaler.inform(w.diagnoses, booting=w.booting)
+        self.scaler.observe(w.t_end, w.p95, w.offered, self.controller.fleet)
+        acts = self.scaler.actions
+        if self.incidents is not None:
+            for a in acts[self._stitched:]:
+                self.incidents.record_action(a)
+        self._stitched = len(acts)
+        self.controller.reconcile(w.t_end)

@@ -1,8 +1,8 @@
 """The ``NodeBackend`` interface: one serving-node contract, two engines.
 
 A backend is what the fleet driver (``cluster_sim.drive_fleet``) and the
-routers see of a node — the same four capabilities regardless of whether
-the node is simulated or real:
+routers see of a node — the same capabilities regardless of whether the
+node is simulated or real:
 
   * ``submit(idx, times, sizes, model_ids)`` — a sorted window of queries
     routed to this node (``idx`` are global trace indices; ``model_ids``
@@ -11,10 +11,14 @@ the node is simulated or real:
   * ``advance_to(t)`` — advance the node to timeline time ``t`` (a no-op
     for simulated nodes, whose completion times are computed analytically
     at submit; a wall-clock wait for live nodes);
-  * ``completed_records()`` — per-query completion facts
-    (``CompletedQuery``), in trace-time coordinates for both engines;
+  * ``completed_records()`` / ``take_new_records()`` — per-query
+    completion facts (``CompletedQuery``), in trace-time coordinates for
+    both engines;
   * ``weight`` — the capacity weight routers consume (per-node achievable
     QPS, from ``Fleet.tune``/``estimate_capacity`` or live calibration).
+
+Whatever the engine, finished queries reach the driver's stages as one
+:class:`Completions` batch.
 
 ``SimNodeBackend`` wraps the stateful numpy fast-engine entry points in
 ``core.simulator`` (``node_pass`` carrying executor/accelerator free times
@@ -94,6 +98,39 @@ class CompletedQuery:
         return (self.t_done - self.t_arrival) * 1e3
 
 
+@dataclasses.dataclass
+class Completions:
+    """Finished queries as arrays, the one form every engine reports in.
+    ``node`` indexes the batch's node list; ``t_arrival`` is when the
+    node got the query (a re-route's instant), ``t_obs`` its original
+    arrival; ``t_released`` defaults to ``t_arrival``; ``t_exec_start``
+    is NaN where not stamped (None: no stamps at all); ``t_done`` is NaN
+    if never completed; ``ok`` is False where the apply_fn failed.  A
+    grouped pass's batch keeps its ``order`` and per-node ``bounds``."""
+    node: np.ndarray
+    idx: np.ndarray
+    t_arrival: np.ndarray
+    t_released: np.ndarray
+    t_exec_start: np.ndarray | None
+    t_done: np.ndarray
+    ok: np.ndarray
+    t_obs: np.ndarray
+    order: np.ndarray | None = None
+    bounds: np.ndarray | None = None
+
+    @classmethod
+    def of_records(cls, recs: list[CompletedQuery],
+                   times: np.ndarray) -> "Completions":
+        """One node's records; ``times`` is the trace."""
+        a = np.array([(r.index, r.t_arrival, r.t_released, r.t_exec_start,
+                       r.t_done, r.error is None) for r in recs],
+                     float).reshape(-1, 6)
+        idx = a[:, 0].astype(np.int64)
+        rel = np.where(np.isnan(a[:, 2]), a[:, 1], a[:, 2])
+        return cls(np.zeros(len(a), np.int64), idx, a[:, 1], rel, a[:, 3],
+                   a[:, 4], a[:, 5] > 0.0, times[idx])
+
+
 class NodeBackend:
     """Base class for serving-node backends (see module docstring).
 
@@ -114,6 +151,8 @@ class NodeBackend:
     # its deadline): the health pass verifies SUSPECT nodes instead of
     # declaring them dead on one bad exchange
     suspect: bool = False
+    # failed RPC attempts so far (remote nodes; see take_retry_s)
+    retry_count: int = 0
 
     @property
     def key(self) -> tuple[str, int]:
@@ -121,33 +160,30 @@ class NodeBackend:
         backend pool are keyed by across fleet resizes."""
         return (self.pool, self.index_in_pool)
 
-    @property
-    def capacity_weight(self) -> float:
-        return self.weight
-
     def start(self, t0: float) -> None:
         """Anchor the backend's timeline at trace time ``t0`` (live
         backends pin the shared wall clock here; sim backends need
         nothing — their free times were seeded at construction)."""
 
     def enable_spans(self) -> None:
-        """Ask the backend to produce span stamps (``t_released``/
-        ``t_exec_start`` on its ``CompletedQuery`` records) from here on.
-        Idempotent; the default is a no-op — live/remote backends always
-        stamp (the wall clock is already being read), while
-        ``SimNodeBackend`` computes exec-starts only when asked so the
-        telemetry-off driver costs exactly what it did before."""
+        """Stamp ``t_released``/``t_exec_start`` from here on (idempotent;
+        live/remote nodes always stamp, a sim node only when asked)."""
 
     def submit(self, idx: np.ndarray, times: np.ndarray, sizes: np.ndarray,
                model_ids: np.ndarray | None = None) -> np.ndarray | None:
         """Accept a sorted window of queries routed to this node.
-
-        Simulated backends return the per-query completion times
-        immediately (the driver folds them into its result arrays without
-        waiting); live backends return ``None`` — their completions
-        surface later through ``completed_records``.
-        """
+        Simulated backends return the completion times; realtime ones
+        return ``None`` (completions surface through the records)."""
         raise NotImplementedError
+
+    def exec_starts(self) -> np.ndarray | None:
+        """Exec-start stamps of the queries of the last ``submit`` that
+        returned completion times (None: not stamped)."""
+        return None
+
+    def take_retry_s(self) -> float:
+        """Wall seconds lost to RPC retries since the last call."""
+        return 0.0
 
     def advance_to(self, t: float) -> None:
         """Advance the node's timeline to trace time ``t``."""
@@ -176,24 +212,17 @@ class NodeBackend:
         return out
 
     def cancel_pending(self, t: float) -> list[PendingQuery]:
-        """Kill the node at trace time ``t``: every accepted query the
-        node had not already completed is forgotten and returned for
-        re-routing, and the backend accepts no further ``submit``.
-        "Already completed" is engine-specific at the boundary: a
-        simulated node keeps analytic completions with ``done <= t``; a
-        live node shuts its ``ServingRuntime`` down mid-run and keeps
-        whatever the runtime had physically finished by the shutdown
-        (including a worker's in-flight request).  Either way, a query
-        is in exactly one of ``completed_records()`` or the returned
-        pending list — nothing is double-counted or lost."""
+        """Kill the node at trace time ``t``: return every accepted query
+        it had not completed (a sim node keeps ``done <= t``, a live one
+        what its runtime finished before shutting down) and accept no
+        further ``submit``.  A query is in exactly one of
+        ``completed_records()`` or the returned list."""
         raise NotImplementedError
 
     def dead(self) -> bool:
-        """Has the execution engine behind this node gone away unplanned?
-        The lifecycle controller's health pass polls this every window;
-        a dead node is retired (orphans re-routed) and — under a
-        ``SelfHealPolicy`` — restarted through BOOTING.  Sim nodes never
-        die on their own; real backends override."""
+        """Has the engine behind this node gone away unplanned?  Polled by
+        the controller's health pass every window (sim nodes never die
+        on their own)."""
         return False
 
     def idle(self, t: float) -> bool:
@@ -204,15 +233,10 @@ class NodeBackend:
         return False
 
     def set_offload_threshold(self, threshold: int | None) -> None:
-        """Re-knob the node's query-size offload threshold mid-run — the
-        write side of the online ``OffloadController``.  The spec is
-        replaced (specs are frozen; router cost caches key on knob
-        values, so a fresh spec object re-prices correctly) and takes
-        effect for *subsequently* submitted windows; work already
-        accepted keeps the knobs it was priced with.  For live/remote
-        backends the spec swap alone is the whole semantics: execution
-        happens on this host's real devices and the threshold only
-        shapes how routers price the node."""
+        """Re-knob the node's offload threshold mid-run (the online
+        ``OffloadController``'s write side) for windows submitted from
+        now on.  The frozen spec is replaced, so router cost caches
+        re-price it; for live/remote nodes that is the whole effect."""
         if threshold == self.spec.offload_threshold:
             return
         self.spec = dataclasses.replace(self.spec,
@@ -251,9 +275,13 @@ class SimNodeBackend(NodeBackend):
                                  np.ndarray | None]] = []
         self._killed = False
         self._spans = False
+        self._starts: np.ndarray | None = None
 
     def enable_spans(self) -> None:
         self._spans = True
+
+    def exec_starts(self) -> np.ndarray | None:
+        return self._starts
 
     def submit(self, idx: np.ndarray, times: np.ndarray, sizes: np.ndarray,
                model_ids: np.ndarray | None = None) -> np.ndarray:
@@ -273,12 +301,17 @@ class SimNodeBackend(NodeBackend):
                 cpu_free=eng.cpu_state.materialize(),
                 acc_free=eng.acc_state.materialize())
             starts = None
+        self._starts = starts
         eng.cpu_state.set_free(cpu_free)
         eng.acc_state.set_free(acc_free)
-        self._chunks.append((np.asarray(idx), np.asarray(times, float),
-                             done, np.asarray(sizes, np.int64), model_ids,
-                             starts))
+        self.record(np.asarray(idx), np.asarray(times, float), done,
+                    np.asarray(sizes, np.int64), model_ids, starts)
         return done
+
+    def record(self, idx, times, done, sizes, model_ids, starts) -> None:
+        """Append one served slice to the node's history (``submit`` and
+        ``submit_grouped`` both serve through it)."""
+        self._chunks.append((idx, times, done, sizes, model_ids, starts))
 
     def completed_records(self) -> list[CompletedQuery]:
         out = []
@@ -293,37 +326,15 @@ class SimNodeBackend(NodeBackend):
                     else float("nan")))
         return out
 
-    def span_arrays(self) -> tuple[np.ndarray, np.ndarray, np.ndarray,
-                                   np.ndarray]:
-        """Vectorized span stamps for every query this node served:
-        ``(global_idx, t_released, t_exec_start, t_done)``.  A simulated
-        query is released the instant it arrives (the analytic pipeline
-        has no batching delay), so ``t_released`` is the submit-time
-        arrival; ``t_exec_start`` is NaN for chunks served before
-        ``enable_spans``."""
-        if not self._chunks:
-            z = np.empty(0)
-            return z.astype(np.int64), z, z, z
-        idx = np.concatenate([c[0] for c in self._chunks]).astype(np.int64)
-        rel = np.concatenate([c[1] for c in self._chunks])
-        done = np.concatenate([c[2] for c in self._chunks])
-        start = np.concatenate([
-            c[5] if c[5] is not None else np.full(len(c[0]), np.nan)
-            for c in self._chunks])
-        return idx, rel, start, done
-
     def idle(self, t: float) -> bool:
         """All analytic completions at or before ``t`` (NaN drops never
         complete and never will — they don't hold the node open)."""
         return all(not np.any(c[2] > t) for c in self._chunks)
 
     def set_offload_threshold(self, threshold: int | None) -> None:
-        """Spec swap plus the simulated execution machinery: the engine's
-        ``SchedulerConfig`` is rebuilt so the *next* submitted window
-        splits CPU/accel work at the new threshold.  ``NodeEngine
-        .set_cfg`` drops the engine's interned class id and invalidates
-        the grouped-pass parts cache — the per-class threshold tables
-        there were built from the old knob."""
+        """Spec swap plus a rebuilt ``SchedulerConfig``: ``NodeEngine
+        .set_cfg`` drops the engine's class id and the grouped-pass
+        parts built from the old knob."""
         if threshold == self.spec.offload_threshold:
             return
         super().set_offload_threshold(threshold)
@@ -376,44 +387,22 @@ def submit_grouped(backends: list[SimNodeBackend], assign: np.ndarray,
                    idx: np.ndarray, times: np.ndarray, sizes: np.ndarray,
                    model_ids: np.ndarray | None = None,
                    engines: list | None = None,
-                   keep_records: bool = True
-                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+                   keep_records: bool = True) -> tuple:
     """One batched numpy advance for a whole routed window of simulated
-    nodes — the fleet-scale replacement for N per-node ``submit`` calls.
+    nodes, in place of N per-node ``submit`` calls.  ``assign`` maps each
+    query to its node in ``backends``; the window is stable-sorted by
+    node (FIFO within each node, as per-node ``submit`` sees it),
+    advanced in one ``node_pass_many`` pass, and — with
+    ``keep_records`` — each node's slice is appended to its history,
+    so ``completed_records``/``cancel_pending``/``idle`` behave as if the
+    node had served it.  Only a caller that has proven nothing reads
+    those histories may drop them (the completion times are unaffected).
+    ``engines`` is an optional cached ``[b.engine for b in backends]``.
 
-    ``assign`` maps each query to its node index in ``backends`` (the
-    router's window assignment); the window is permuted node-segmented
-    (stable sort, preserving FIFO arrival order within each node — the
-    same order per-node ``submit`` would have seen), advanced in one
-    ``node_pass_many`` pass, and each node's slice is appended to its own
-    ``_chunks`` history — so ``completed_records`` / ``cancel_pending`` /
-    ``idle`` / ``span_arrays`` behave exactly as if the node had served
-    the window itself.  Span stamps are computed iff any node has them
-    enabled (the driver enables all-or-none).
-
-    Returns ``(done, order, seg_bounds, exec_starts)``: per-query
-    completion times aligned with the *input* window order, the
-    node-segmented permutation and its per-node end offsets (so the
-    caller's telemetry fold can reuse the segmentation instead of
-    re-sorting), and — when spans are enabled — each query's first
-    executor dispatch time in input order (else ``None``), letting the
-    driver stamp the span table inline per window instead of re-walking
-    chunk histories at end of run.
-
-    ``engines`` is an optional precomputed ``[b.engine for b in
-    backends]`` — a steady-state driver caches it per serving list so
-    the per-window work touches only nodes that actually received
-    queries.  When omitted (or on any doubt) it is rebuilt here, with a
-    dead-node check.
-
-    ``keep_records=False`` skips the per-node ``_chunks`` scatter — the
-    largest per-window cost of the grouped layout (hundreds of array
-    slices a window).  Only a driver that has proven the history has no
-    reader may pass it: no telemetry spans, no scheduled kills or chaos
-    (``cancel_pending`` rolls chunks back), no autoscaler/heal
-    (``idle`` reads them), no caller-owned backends
-    (``completed_records`` is public surface).  The completion times
-    themselves are unaffected — chunks are bookkeeping, not state.
+    Returns ``(done, order, seg_bounds, exec_starts)``: completion times
+    in input order, the node-segmented permutation and its per-node end
+    offsets (for the caller's per-node folds), and — when spans are
+    enabled — each query's first executor dispatch time (else None).
     """
     assign = np.asarray(assign, np.int64)
     order = np.argsort(assign, kind="stable")
@@ -451,8 +440,6 @@ def submit_grouped(backends: list[SimNodeBackend], assign: np.ndarray,
             s, e = int(seg_starts[i]), int(seg_bounds[i])
             st = starts_p[s:e] if (starts_p is not None and b._spans) \
                 else None
-            b._chunks.append((p_idx[s:e], p_times[s:e], done_p[s:e],
-                              p_sizes[s:e],
-                              p_mids[s:e] if p_mids is not None else None,
-                              st))
+            b.record(p_idx[s:e], p_times[s:e], done_p[s:e], p_sizes[s:e],
+                     p_mids[s:e] if p_mids is not None else None, st)
     return done, order, seg_bounds, starts

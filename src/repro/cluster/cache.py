@@ -25,12 +25,9 @@ driver inserts each completed miss at its completion time, so a result
 computed *after* a query arrived can never answer it (no time travel on
 the virtual timeline), and expired entries drop on first touch.
 
-The driver integration lives in ``cluster_sim.drive_fleet(cache=...)``:
-hits complete analytically at ``t + hit_latency_s`` in sim (and
-short-circuit submission entirely in live/remote), misses flow to the
-router unchanged, and hit/miss/eviction counters stream into the
-telemetry registry with a ``cache`` span component keeping latency
-attribution closed.
+:class:`CacheStage` is the driver's layer (``drive_fleet(cache=...)``):
+hits complete at ``t + hit_latency_s`` without touching a node, misses
+flow to the router, and hits get a ``cache`` span component.
 """
 from __future__ import annotations
 
@@ -39,7 +36,9 @@ from collections import OrderedDict
 
 import numpy as np
 
-__all__ = ["CacheConfig", "FleetCache"]
+from repro.obs.telemetry import FleetStage
+
+__all__ = ["CacheConfig", "FleetCache", "CacheStage"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,3 +181,48 @@ class FleetCache:
                 self.evictions += 1
             shard[k] = [ts, 0] if lfu else ts
             self.inserts += 1
+
+
+class CacheStage(FleetStage):
+    """The cache in the driver loop: a window's queries are looked up by
+    key before routing; results are committed at their completion times
+    (a sim window's misses at its boundary, a realtime node's as they are
+    polled), so in-window repeats miss and a single-window run sees no
+    hits.  With a ``registry``, counters and gauges are booked."""
+
+    def __init__(self, cache: FleetCache, keys: np.ndarray, registry=None):
+        self.cache, self.keys, self.reg = cache, keys, registry
+        self._seen = {"hits": 0, "misses": 0, "evictions": 0}
+
+    def start(self, run) -> None:
+        self.run = run
+        self._miss = np.empty(0, np.int64)
+
+    def lookup(self, idx, t):
+        hit = self.cache.lookup_many(self.keys[idx], t)
+        self._miss = idx[~hit]
+        if not hit.any():
+            return None
+        lat = self.cache.cfg.hit_latency_s
+        return hit, t[hit] + lat, np.full(int(hit.sum()), lat * 1e3)
+
+    def completed(self, rec, nodes) -> None:
+        if self.run.controller.realtime:
+            self.cache.insert_many(self.keys[rec.idx[rec.ok]],
+                                   rec.t_done[rec.ok])
+
+    def end_window(self, w) -> None:
+        if not self.run.controller.realtime and len(self._miss):
+            # insert_many skips NaN drops itself
+            self.cache.insert_many(self.keys[self._miss],
+                                   self.run.done[self._miss])
+        if self.reg is None:
+            return
+        st = self.cache.stats()
+        for k in ("hits", "misses", "evictions"):
+            d = st[k] - self._seen[k]
+            if d:
+                self.reg.counter(f"cache_{k}").inc(d)
+                self._seen[k] = st[k]
+        self.reg.gauge("cache_hit_rate").set(self.cache.hit_rate)
+        self.reg.gauge("cache_size").set(st["size"])

@@ -104,6 +104,11 @@ class FleetFaults:
     kills: tuple[NodeKill, ...] = ()
     reroute: bool = True
 
+    def injections(self) -> list:
+        """Chaos deliverables for window boundaries (a ``ChaosPlan``'s;
+        a plain kill plan has none)."""
+        return []
+
 
 @dataclasses.dataclass(frozen=True)
 class SelfHealPolicy:
@@ -195,8 +200,7 @@ class FleetController:
         # transport/boot chaos injections (a ChaosPlan's hangs + garbles),
         # delivered to backends' inject_chaos hooks at window boundaries;
         # plain FleetFaults has none
-        inj = getattr(self.faults, "injections", None)
-        self._injections = list(inj()) if callable(inj) else []
+        self._injections = list(self.faults.injections())
         self._next_inject = 0
         self._restarts: dict[tuple, int] = {}  # key → heal budget used
         self._owned = fleet is not None
@@ -413,11 +417,11 @@ class FleetController:
         self._restarts[key] = used + 1
         self._dead[key] = t + heal.delay_s(used)
 
-    def _node_died(self, key: tuple, t: float) -> list[PendingQuery]:
-        """Retire a node that died *unplanned* (health probe or a failed
-        submit): write the death back to the ledger, surrender its
-        unfinished queries for re-routing, and let the heal policy decide
-        whether it restarts."""
+    def node_died(self, key: tuple, t: float) -> list[PendingQuery]:
+        """Retire a node that died *unplanned* (the health probe, or a
+        ``BackendDied`` from submit): write the death to the ledger,
+        surrender its unfinished queries for re-routing, and let the heal
+        policy decide whether it restarts."""
         node = self._nodes.pop(key, None)
         if node is None:
             return []
@@ -440,12 +444,6 @@ class FleetController:
             self._schedule_restart(key, t)
         return orphans
 
-    def node_died(self, key: tuple, t: float) -> list[PendingQuery]:
-        """Public form of the unplanned-death path, for the driver: a
-        ``submit``/poll raised ``BackendDied`` mid-window, before the
-        next health pass would have noticed."""
-        return self._node_died(key, t)
-
     def _health_pass(self, t: float) -> list[PendingQuery]:
         """Poll every node's health: dead backends are retired (their
         orphans re-routed, heal policy deciding on a restart); SUSPECT
@@ -459,7 +457,7 @@ class FleetController:
             except Exception:
                 is_dead = True
             if is_dead:
-                orphans += self._node_died(key, t)
+                orphans += self.node_died(key, t)
                 continue
             if getattr(b, "suspect", False) and node.state in (
                     NodeState.SERVING, NodeState.DRAINING):
@@ -471,7 +469,7 @@ class FleetController:
                     node.state = prev    # false alarm: reinstated
                     self._transition(t, key, prev)
                 else:
-                    orphans += self._node_died(key, t)
+                    orphans += self.node_died(key, t)
         return orphans
 
     def _dispatch_chaos(self, t: float) -> None:

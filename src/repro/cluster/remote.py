@@ -923,6 +923,10 @@ class BootingRemoteBackend(NodeBackend):
     def take_retry_s(self) -> float:
         return self._inner.take_retry_s() if self._inner is not None else 0.0
 
+    @property
+    def retry_count(self) -> int:
+        return self._inner.retry_count if self._inner is not None else 0
+
     def completed_records(self) -> list[CompletedQuery]:
         return self._inner.completed_records() if self._inner is not None \
             else []

@@ -7,11 +7,10 @@ stamps from whichever engine served each query), the
 latency, error / re-route / retry counters), and the
 :class:`FleetTimeline` of per-window registry snapshots.  See the module
 docstrings of ``spans``/``metrics``/``attribution``/``export`` for the
-individual layers, and ``python -m repro.obs.dump`` for the artifact CLI.
+individual layers, ``telemetry``/``slo`` for the fleet driver's stages
+that fill them, and ``python -m repro.obs.dump`` for the artifact CLI.
 """
 from __future__ import annotations
-
-import dataclasses
 
 from repro.obs.attribution import (AttributionReport, PercentileAttribution,
                                    latency_attribution)
@@ -25,6 +24,7 @@ from repro.obs.slo import (DEFAULT_RULES, AlertEvent, BurnRateRule,
                            ControlAction, Incident, IncidentLog, SloEngine,
                            SloObjective)
 from repro.obs.spans import COMPONENTS, STAGES, QuerySpan, SpanTable
+from repro.obs.telemetry import RunTelemetry
 
 __all__ = [
     "AttributionReport", "PercentileAttribution", "latency_attribution",
@@ -37,19 +37,3 @@ __all__ = [
     "Incident", "IncidentLog", "SloEngine", "SloObjective",
     "RunTelemetry",
 ]
-
-
-@dataclasses.dataclass
-class RunTelemetry:
-    """Everything one ``drive_fleet(telemetry=True)`` run observed."""
-    spans: SpanTable
-    registry: MetricsRegistry
-    timeline: FleetTimeline
-
-    def attribution(self, percentiles: tuple[float, ...] = (50.0, 95.0, 99.0),
-                    band_frac: float = 0.02) -> AttributionReport:
-        return latency_attribution(self.spans, percentiles,
-                                   band_frac=band_frac)
-
-    def prometheus(self) -> str:
-        return to_prometheus(self.registry)

@@ -272,10 +272,7 @@ class Histogram:
         self.window.observe(v)
 
     def observe_many(self, values: np.ndarray) -> None:
-        # bucketize once, absorb twice (same rel_err -> same buckets)
-        d = self.total._digest(values)
-        self.total._absorb(d)
-        self.window._absorb(d)
+        observe_fanout(values, self)
 
 
 def observe_fanout(values: np.ndarray, *hists: Histogram) -> None:

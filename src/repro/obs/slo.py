@@ -41,12 +41,16 @@ import collections
 import dataclasses
 import math
 
+import numpy as np
+
 from repro.obs.attribution import latency_attribution
 from repro.obs.diagnose import BreachDiagnoser, Diagnosis
 from repro.obs.spans import COMPONENTS
+from repro.obs.telemetry import TelemetryStage
 
 __all__ = ["SloObjective", "BurnRateRule", "DEFAULT_RULES", "AlertEvent",
-           "ControlAction", "Incident", "IncidentLog", "SloEngine"]
+           "ControlAction", "Incident", "IncidentLog", "SloEngine",
+           "SloStage"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -411,3 +415,66 @@ class SloEngine:
             if mask.any():
                 inc.attribution = latency_attribution(
                     spans, (obj.percentile,), mask=mask)
+
+
+class SloStage(TelemetryStage):
+    """``drive_fleet(slo=engine)``: telemetry, plus ``span_<component>_ms``
+    folds of every batch, hit, re-route and retry; the engine evaluates
+    each boundary's snapshot (its diagnoses go to the autoscaler) and is
+    finalized on the span table.  Latency runs from a query's original
+    arrival, so re-route wait reaches the window sketches."""
+
+    def __init__(self, engine: SloEngine, times, model_ids=None):
+        super().__init__(times, model_ids)
+        self.engine = engine
+        self.times = times
+        self.queueing = self.reg.histogram("span_queueing_ms")
+        self.service = self.reg.histogram("span_service_ms")
+
+    def _origin(self, rec):
+        return rec.t_obs
+
+    def start(self, run) -> None:
+        self.engine.reset()
+
+    def answered(self, idx, t_done, lat_ms) -> None:
+        super().answered(idx, t_done, lat_ms)
+        self.reg.histogram("span_cache_ms").observe_many(lat_ms)
+
+    def completed(self, rec, nodes) -> None:
+        super().completed(rec, nodes)
+        if rec.t_exec_start is None:
+            return
+        # a query with no exec stamp folds queueing into service
+        keep = rec.ok & ~np.isnan(rec.t_done)
+        rel, start, done = (rec.t_released[keep], rec.t_exec_start[keep],
+                            rec.t_done[keep])
+        have = ~np.isnan(start)
+        self.queueing.observe_many(np.where(have, start - rel, 0.0) * 1e3)
+        self.service.observe_many(np.where(have, done - start, done - rel)
+                                  * 1e3)
+
+    def exchanged(self, b, idx) -> float:
+        s = super().exchanged(b, idx)
+        if s > 0.0 and idx is not None:
+            # every query in the frame shared the stall
+            self.reg.histogram("span_retry_ms").observe_many(
+                np.full(len(idx), s * 1e3))
+        return s
+
+    def rerouted(self, idx, t) -> None:
+        super().rerouted(idx, t)
+        self.reg.histogram("span_reroute_ms").observe_many(
+            (t - self.times[idx]) * 1e3)
+
+    def shed(self, idx) -> None:
+        super().shed(idx)
+        self.reg.counter("queries_shed").inc(len(idx))
+
+    def end_window(self, w) -> None:
+        super().end_window(w)
+        w.diagnoses = self.engine.on_window(w.snap)
+
+    def finish(self, run) -> None:
+        super().finish(run)
+        self.engine.finalize(self.spans, t_end=run.horizon)

@@ -128,9 +128,7 @@ class SpanTable:
     def record(self, index: int, released: float, exec_start: float,
                done: float) -> None:
         """Backend-reported stamps for one query (NaN = not stamped)."""
-        self.t_released[index] = released
-        self.t_exec_start[index] = exec_start
-        self.t_done[index] = done
+        self.record_many(index, released, exec_start, done)
 
     def record_many(self, idx: np.ndarray, released: np.ndarray,
                     exec_start: np.ndarray, done: np.ndarray) -> None:

@@ -446,10 +446,12 @@ def test_remote_crash_storm_self_heals_end_to_end():
 
     clock = WallClock()
     with WorkerSupervisor() as sup:
-        # ~200ms of GIL-held work per query against ~100ms per-node
-        # arrivals: the victim is over capacity and has a queue when the
-        # kill lands, so real orphans re-route
-        factory = RemoteBackendFactory("pybusy:400000", sup,
+        # 200ms of sleep per query (4 rows × 50ms) against ~100ms
+        # per-node arrivals: the victim is over capacity and has a queue
+        # when the kill lands, so real orphans re-route.  A sleep, not
+        # pybusy's CPU work, so the backlog does not depend on how fast
+        # or how loaded the host is
+        factory = RemoteBackendFactory("iosleep:50000", sup,
                                        device=_canned_device(2e-1),
                                        batch_size=16, max_bucket=64,
                                        clock=clock, async_boot=True)
